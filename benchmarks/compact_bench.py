@@ -161,6 +161,9 @@ def bench_scale(kind: str, n: int, n_segments: int, repeats: int,
 
 
 def main(argv=None) -> None:
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="64 Ki rows at 2/4/8 segments (the CI gate)")

@@ -144,6 +144,9 @@ def kernel_microbench(nblocks=256, r=64, B=1024, reps=5, smoke=False):
 
 
 def main(argv=None):
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="seconds-scale CI variant with parity assertions")

@@ -63,6 +63,8 @@ def _build_json_section():
 
 
 def main() -> None:
+    from repro.launch import compile_cache
+
     from . import (
         compact_bench,
         fm_query_bench,
@@ -71,6 +73,7 @@ def main() -> None:
         table2_bwt,
     )
 
+    compile_cache.enable()
     table2_bwt.main([])
     _build_json_section()
     sort_bench.main()

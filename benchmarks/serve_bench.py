@@ -254,6 +254,9 @@ def bench_scale(label, kind, n, n_segments, n_requests, clients, cfg, rng):
 
 def main(argv=None):
     from repro.configs.bwt_index import CONFIG, reduced
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",

@@ -31,7 +31,7 @@ import os, json
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core.dist_sort import ShardInfo, bitonic_sort_sharded, samplesort_sharded
 from repro.launch.roofline import collective_bytes
 
@@ -87,6 +87,9 @@ def collective_volumes():
     # to the subprocess via PYTHONPATH, so the probe imports `repro` no
     # matter where the benchmark is invoked from
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    # the probe counts HLO volume on 8 virtual CPU devices; it must never
+    # reach for the accelerator, which the parent process may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = _SRC + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""
     )
@@ -139,6 +142,9 @@ def local_sort_times(n=1 << 18, reps=3):
 
 
 def main():
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     vols = collective_volumes()
     locals_ = local_sort_times()
     print("sortbench,engine,bytes_per_device,collective_ops,local_sort_us")

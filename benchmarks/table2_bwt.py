@@ -130,6 +130,9 @@ def write_json(rows, path):
 
 
 def main(argv=None):
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="tiny sizes + 1 rep (CI build-bench smoke)")

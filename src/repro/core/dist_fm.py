@@ -32,7 +32,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map
+from jax import shard_map
 from ..kernels import ops
 from ..kernels.rank_select import pack_words, packed_bits
 from .fm_index import PAD, build_sa_samples, sample_lookup
@@ -215,6 +215,7 @@ def _count_jit(index_arrays, patterns, index_static, mesh):
         fn, mesh=mesh,
         in_specs=(P(AXIS), P(AXIS), P(AXIS) if bits else P(), P(), P()),
         out_specs=(P(), P()),
+        check_vma=not ops.kernels_on_chip(),
     )(bwt, occ_samples, fused, c_array, patterns)
     return jnp.maximum(ep - sp, 0)
 
@@ -288,6 +289,7 @@ def _locate_jit(index_arrays, patterns, index_static, k, mesh):
         in_specs=(P(AXIS), P(AXIS), P(AXIS) if bits else P(), P(),
                   P(), P(), P(), P()),
         out_specs=(P(), P()),
+        check_vma=not ops.kernels_on_chip(),
     )(bwt, occ_samples, fused, c_array, marks, mark_ranks, vals, patterns)
 
 

@@ -17,8 +17,8 @@ Spark-shuffle equivalents from DESIGN.md §4:
   "Shifting" map (rank[i + h]) with two neighbour ppermutes.
 
 All collective permutations use static perms (ppermute requirement); the
-prefix-doubling driver therefore unrolls over ``h`` (h is a static integer,
-known per round).
+prefix-doubling driver's traced pairing distance ``h`` selects among the
+``P`` static shard offsets (``shift_sharded``).
 
 Fused-key layout (PR 2): the doubling driver packs each (rank, rank[i+h])
 pair into 1-2 **uint32 key words** (``core.keypack``), so both engines sort
@@ -105,28 +105,40 @@ def exclusive_max_sharded(
 # ---------------------------------------------------------------------------
 
 def shift_sharded(
-    info: ShardInfo, x: jax.Array, h: int, fill: int
+    info: ShardInfo, x: jax.Array, h, fill: int
 ) -> jax.Array:
     """out[g] = x[g + h] for global g, ``fill`` past the end.
 
-    ``h`` is static (one prefix-doubling round = one power of two), so the
-    ppermute perms are static: the data for any destination shard lives on at
-    most two source shards (DESIGN.md §2 table, "distributed roll").
+    The data for any destination shard lives on at most two source shards
+    (DESIGN.md §2 table, "distributed roll").  A static ``h`` (Python int)
+    fixes their ppermute perms.  A traced ``h`` (the prefix-doubling loop's
+    pairing distance) picks the pair of whole-shard offsets with a
+    ``lax.switch`` over the ``P`` possible ones and rolls by ``h mod m``
+    with a dynamic slice.
     """
     P, m = info.parts, info.part_size
-    q, rs = divmod(h, m)
-    if q >= P:  # the whole shard is past the end
-        return jnp.full_like(x, fill)
 
-    # I receive the shard of device (me + q); sender i sends to (i - q).
-    perm_a = [(i, (i - q) % P) for i in range(P)]
-    a = lax.ppermute(x, info.axis, perm_a) if q % P != 0 else x
-    if rs == 0:
-        out = a
+    def ahead(j: int) -> jax.Array:
+        """The shard of device (me + j): sender i sends to (i - j)."""
+        if j % P == 0:
+            return x
+        return lax.ppermute(x, info.axis, [(i, (i - j) % P) for i in range(P)])
+
+    if isinstance(h, int):
+        q, rs = divmod(h, m)
+        if q >= P:  # the whole shard is past the end
+            return jnp.full_like(x, fill)
+        a = ahead(q)
+        out = a if rs == 0 else jnp.concatenate([a[rs:], ahead(q + 1)[:rs]])
     else:
-        perm_b = [(i, (i - q - 1) % P) for i in range(P)]
-        b = lax.ppermute(x, info.axis, perm_b)
-        out = jnp.concatenate([a[rs:], b[:rs]])
+        h = jnp.asarray(h, jnp.int32)
+        # q >= P leaves every slot past the end; any branch then serves
+        pair = lax.switch(
+            jnp.minimum(h // m, P - 1),
+            [lambda j=j: jnp.concatenate([ahead(j), ahead(j + 1)])
+             for j in range(P)],
+        )
+        out = lax.dynamic_slice(pair, (h % m,), (m,))
 
     gidx = _me(info) * m + jnp.arange(m, dtype=jnp.int32)
     return jnp.where(gidx + h < info.n, out, fill)

@@ -30,8 +30,9 @@ the PR-2 build-engine optimisations (fused keys / q-gram init / discarding):
                and re-ranking touches only the shrinking active set.
     Scatter    route new ranks + active flags back to index order
                (sort-by-permutation or capacity-bounded all_to_all)
-    Iterate    h <- q, 2q, 4q, ... unrolled (static ppermute perms), each
-               round guarded by ``lax.cond`` on the no-actives-left flag.
+    Iterate    h <- q, 2q, 4q, ... in a ``lax.while_loop`` that stops on
+               the no-actives-left flag; the traced h picks the shift's
+               static ppermute perms with a ``lax.switch``.
 
 Everything here runs INSIDE ``shard_map``; ``build_isa_sharded`` /
 ``build_bwt_sharded`` are the jit-able host-level entry points.  The
@@ -50,9 +51,10 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map
+from jax import shard_map
 
 from . import keypack
+from ..kernels import ops as kernel_ops
 from .dist_sort import (
     ShardInfo,
     bitonic_sort_sharded,
@@ -63,7 +65,7 @@ from .dist_sort import (
     scatter_to_index_samplesort,
     shift_sharded,
 )
-from .suffix_array import OVERFLOW_RANK, resolve_local_sort
+from .suffix_array import OVERFLOW_RANK, prefix_max, resolve_local_sort
 
 BITONIC = "bitonic"
 SAMPLESORT = "samplesort"
@@ -157,7 +159,7 @@ def dist_rerank(
     neq_pair = neq_pair.at[0].set(jnp.where(prev_exists, neq_pair[0], True))
 
     def head_pos(heads):
-        local = lax.associative_scan(jnp.maximum, jnp.where(heads, gpos, -1))
+        local = prefix_max(jnp.where(heads, gpos, -1))
         carry = exclusive_max_sharded(info, local[-1], identity=-1)
         return jnp.maximum(local, carry)
 
@@ -260,7 +262,7 @@ def dist_qgram_init(info: ShardInfo, cfg: DistSAConfig, eng: str,
 
 
 def _doubling_round(info: ShardInfo, cfg: DistSAConfig, eng: str,
-                    spec: keypack.PairSpec, h: int, rank, gidx, active):
+                    spec: keypack.PairSpec, h, rank, gidx, active):
     """One fused-key prefix-doubling round over the active suffixes;
     returns (new_rank, new_active, done)."""
     r2 = shift_sharded(info, rank, h, OVERFLOW_RANK)
@@ -359,16 +361,21 @@ def dist_isa_local(
     remaining = lax.psum(jnp.sum(active.astype(jnp.int32)), info.axis)
     done = jnp.asarray(info.n <= 1) | (remaining == 0) | bad
     rounds = cfg.rounds if cfg.rounds is not None else num_rounds(info.n, h0)
-    for r in range(rounds):
-        h = h0 * (2 ** r)
 
-        def do(args, h=h):
-            rank, active, done = args
-            return _doubling_round(info, cfg, eng, spec, h, rank, gidx, active)
+    def more(st):
+        r, _, _, done = st
+        return (r < rounds) & ~done
 
-        rank, active, done = lax.cond(
-            done, lambda a: a, do, (rank, active, done)
-        )
+    def round_(st):
+        r, rank, active, _ = st
+        h = jnp.left_shift(jnp.int32(h0), r)
+        return (r + 1, *_doubling_round(info, cfg, eng, spec, h, rank, gidx,
+                                        active))
+
+    # one compiled round, iterated: the program stays the size of a round
+    # whatever ceil(log2 n) is
+    _, rank, _, _ = lax.while_loop(
+        more, round_, (jnp.int32(0), rank, active, done))
     return rank
 
 
@@ -414,7 +421,8 @@ def _isa_jit(s, sigma, cfg, mesh_axis_size, mesh):
     info = ShardInfo(cfg.axis, mesh_axis_size, s.shape[0] // mesh_axis_size)
     fn = functools.partial(dist_isa_local, info, cfg, sigma=sigma)
     return shard_map(
-        fn, mesh=mesh, in_specs=P(cfg.axis), out_specs=P(cfg.axis)
+        fn, mesh=mesh, in_specs=P(cfg.axis), out_specs=P(cfg.axis),
+        check_vma=not kernel_ops.kernels_on_chip(),
     )(s)
 
 
@@ -441,6 +449,7 @@ def _bwt_jit(s, isa, cfg, mesh_axis_size, mesh):
         mesh=mesh,
         in_specs=(P(cfg.axis), P(cfg.axis)),
         out_specs=(P(cfg.axis), P(cfg.axis), P()),
+        check_vma=not kernel_ops.kernels_on_chip(),
     )(s, isa)
 
 

@@ -18,7 +18,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from . import alphabet as al
 from .bwt import bwt_from_sa
@@ -199,7 +199,9 @@ def build_index(
     parts = mesh.shape[sa_config.axis]
     s, sigma = prepare_tokens(tokens, parts * sample_rate, sigma,
                               reserve_pad)
-    s_dev = jnp.asarray(s)
+    # shard the text straight from the host: no device holds all of it
+    s_dev = jax.device_put(
+        s, NamedSharding(mesh, PartitionSpec(sa_config.axis)))
     cfg = sa_config
     for attempt in range(max_retries):
         isa = build_isa_sharded(s_dev, mesh, cfg, sigma=sigma)
@@ -211,11 +213,7 @@ def build_index(
             f"samplesort capacity overflow after {max_retries} retries "
             f"(factor {cfg.capacity_factor})"
         )
-    from jax.sharding import NamedSharding, PartitionSpec
-    s_sharded = jax.device_put(
-        s_dev, NamedSharding(mesh, PartitionSpec(cfg.axis))
-    )
-    sa, bwt_arr, row = _bwt_jit(s_sharded, isa, cfg, parts, mesh)
+    sa, bwt_arr, row = _bwt_jit(s_dev, isa, cfg, parts, mesh)
     fm = build_dist_fm_index(bwt_arr, row, mesh, sigma=sigma,
                              sample_rate=sample_rate, pack=pack,
                              compress_sa=compress_sa,

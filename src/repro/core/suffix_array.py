@@ -82,6 +82,27 @@ def rerank_from_sorted(
     return lax.associative_scan(jnp.maximum, heads), jnp.all(flags)
 
 
+def prefix_max(x: jax.Array, width: int = 1024) -> jax.Array:
+    """Inclusive running max of an int32[n] array.
+
+    A flat ``lax.associative_scan`` over tens of millions of elements
+    takes minutes to compile for the TPU; this form — a running max inside
+    ``width``-wide rows, then the running max of the row maxima (same
+    recursion) carried into each row — compiles in seconds and returns the
+    same values."""
+    n = x.shape[0]
+    if n <= width:
+        return lax.cummax(x)
+    lo = jnp.iinfo(x.dtype).min
+    rows = lax.cummax(
+        jnp.pad(x, (0, (-n) % width), constant_values=lo).reshape(-1, width),
+        axis=1,
+    )
+    carried = prefix_max(rows[:, -1], width)
+    before = jnp.concatenate([jnp.full((1,), lo, x.dtype), carried[:-1]])
+    return jnp.maximum(rows, before[:, None]).reshape(-1)[:n]
+
+
 def shifted_ranks(rank: jax.Array, h: jax.Array) -> jax.Array:
     """rank2[i] = rank[i+h] for i+h < n else OVERFLOW_RANK (paper's Shifting
     and Pairing, expressed as a roll + mask instead of a keyed join)."""
@@ -183,9 +204,7 @@ def _qgram_init(s, fpw: int, bits: int, words: int, engine: str):
     for k in ks:
         neq = neq | (k[1:] != k[:-1])
     head = jnp.concatenate([jnp.ones(1, bool), neq])
-    ranks_sorted = lax.associative_scan(
-        jnp.maximum, jnp.where(head, idx, 0)
-    ).astype(jnp.int32)
+    ranks_sorted = prefix_max(jnp.where(head, idx, 0))
     succ_head = jnp.concatenate([head[1:], jnp.ones(1, bool)])
     active_sorted = ~(head & succ_head)
     rank = jnp.zeros(n, jnp.int32).at[perm].set(ranks_sorted)
@@ -242,10 +261,8 @@ def _fast_round(n: int, cap: int, engine: str):
         neq2 = jnp.concatenate([jnp.ones(1, bool), r2s[1:] != r2s[:-1]])
         r1_head = valid_s & neq1
         pair_head = valid_s & (neq1 | neq2)
-        r1_pos = lax.associative_scan(
-            jnp.maximum, jnp.where(r1_head, slot, -1))
-        pair_pos = lax.associative_scan(
-            jnp.maximum, jnp.where(pair_head, slot, -1))
+        r1_pos = prefix_max(jnp.where(r1_head, slot, -1))
+        pair_pos = prefix_max(jnp.where(pair_head, slot, -1))
         new_rank = r1s + (pair_pos - r1_pos)
 
         succ_head = (

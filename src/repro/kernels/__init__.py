@@ -10,7 +10,6 @@ from .ops import (  # noqa: F401
     radix_hist,
     radix_sort,
     rank_packed,
-    rank_select,
     rank_unpacked,
     rerank_scan,
 )

@@ -14,7 +14,7 @@ import jax.numpy as jnp
 
 from . import ref
 from .char_histogram import char_histogram_pallas
-from .radix_hist import radix_hist_pallas
+from .radix_hist import GROUP, radix_hist_pallas
 from .radix_sort import radix_sort_jnp, radix_sort_pallas
 from .rank_select import rank_packed_jnp, rank_packed_pallas, rank_select_pallas
 from .rerank_scan import rerank_scan_pallas
@@ -24,11 +24,20 @@ def _interpret_default() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _rank_impl_default() -> str:
-    """Build-time backend selection for the rank hot path: the real Pallas
-    kernel on TPU, the pure-jnp popcount fallback elsewhere ("interpret" is
-    opt-in for kernel parity tests — far too slow to serve from)."""
-    return "pallas" if jax.default_backend() == "tpu" else "jnp"
+def kernels_on_chip() -> bool:
+    """True where the backend defaults route the rank and local-sort hot
+    paths to the Pallas kernels.  Their outputs carry no varying-manual-axes
+    type, so a ``shard_map`` body that calls them runs with ``check_vma``
+    off; everywhere else the check stays on."""
+    return jax.default_backend() == "tpu"
+
+
+def _impl_default() -> str:
+    """Build-time backend selection for the rank and local-sort hot paths:
+    the real Pallas kernels on TPU, the pure-jnp fallbacks elsewhere
+    ("interpret" is opt-in for kernel parity tests — far too slow to serve
+    or build from)."""
+    return "pallas" if kernels_on_chip() else "jnp"
 
 
 @functools.partial(jax.jit, static_argnames=("sigma", "block_rows", "interpret"))
@@ -72,13 +81,6 @@ def rerank_scan(r1, r2, *, block: int = 512, interpret: bool | None = None):
         ranks = ranks[:n]
         ngroups = ngroups - 1  # the padding group
     return ranks, ngroups[0]
-
-
-def _sort_impl_default() -> str:
-    """Local-sort backend for the build hot path: the Pallas radix pipeline
-    on TPU, the pure-jnp counting sort elsewhere ("interpret" is opt-in for
-    kernel parity tests)."""
-    return "pallas" if jax.default_backend() == "tpu" else "jnp"
 
 
 COMPARE = "compare"
@@ -129,13 +131,13 @@ def radix_sort(operands, *, num_keys: int, key_bits, block: int = 1024,
     ``impl``: None -> backend default ("pallas" on TPU, "jnp" elsewhere);
     "interpret" runs the kernels in interpret mode for parity testing.
     """
-    impl = _sort_impl_default() if impl is None else impl
+    impl = _impl_default() if impl is None else impl
     operands = tuple(operands)
     key_bits = tuple(key_bits)
     if impl == "jnp":
         return radix_sort_jnp(operands, num_keys, key_bits)
     n = operands[0].shape[0]
-    pad = (-n) % block
+    pad = (-n) % (block * GROUP)
     if pad:
         # pads go AFTER real data; per-pass stability keeps them there even
         # when a real key saturates its field (ties resolve to input order)
@@ -160,30 +162,55 @@ def radix_hist(keys, shift: int, *, block: int = 1024,
                interpret: bool | None = None):
     """Per-block 8-bit digit histograms: uint32[n] -> int32[n/block, 256]
     of counts of ``(keys >> shift) & 0xFF``.  ``block`` must divide n
-    (callers tile); interpret mode auto-selected off-TPU."""
+    (callers tile); interpret mode auto-selected off-TPU.  Whole zero
+    blocks pad the keys to the kernel's ``GROUP`` blocks per grid step;
+    their rows are trimmed."""
     interpret = _interpret_default() if interpret is None else interpret
-    return radix_hist_pallas(keys, shift, block=block, interpret=interpret)
+    nblocks = keys.shape[0] // block
+    pad = (-nblocks) % GROUP * block
+    if pad:
+        keys = jnp.concatenate([keys, jnp.zeros((pad,), keys.dtype)])
+    out = radix_hist_pallas(keys, shift, block=block, interpret=interpret)
+    return out[:nblocks]
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def rank_select(bwt_blocks, block_idx, c, cutoff, *, interpret: bool | None = None):
-    """In-block FM rank counts over unpacked symbols (scalar-prefetch
-    gather kernel; interpret mode auto-selected off-TPU).
+# queries per row-gather kernel call: the call's block/symbol/cutoff
+# vectors are scalar-prefetched into SMEM (1 MiB on a v5e), 12 B per query
+GATHER_CHUNK = 1 << 15
 
-    ``bwt_blocks`` int32[n_blocks, r]; per query i the result counts
-    occurrences of symbol ``c[i]`` in the first ``cutoff[i]`` positions of
-    block ``block_idx[i]`` — all int32[B] -> int32[B]."""
-    interpret = _interpret_default() if interpret is None else interpret
-    return rank_select_pallas(
-        bwt_blocks, block_idx, c, cutoff, interpret=interpret
-    )
+
+def _gather_queries(kernel, block_idx, c, cutoff, queries_per_step: int,
+                    **kw):
+    """Pad int32[B] queries to the kernel's per-step multiple and trim the
+    answers.  ``queries_per_step`` clamps to the next power of two >= B, so
+    scalar walks (the BWT-merge interleave walk issues one- and two-query
+    dispatches per step) don't pay for a full lane row of grid steps.
+    Batches above ``GATHER_CHUNK`` run as a ``lax.map`` over chunks."""
+    B = block_idx.shape[0]
+    Q = min(queries_per_step, 1 << max(0, B - 1).bit_length())
+    unit = GATHER_CHUNK if B > GATHER_CHUNK else Q
+    pad = (-B) % unit
+    if pad:
+        z = jnp.zeros(pad, jnp.int32)
+        block_idx, c, cutoff = (
+            jnp.concatenate([a, z]) for a in (block_idx, c, cutoff)
+        )
+
+    def call(q):
+        return kernel(block_idx=q[0], c=q[1], cutoff=q[2],
+                      queries_per_step=Q, **kw)
+
+    if B <= GATHER_CHUNK:
+        return call((block_idx, c, cutoff))[:B]
+    chunks = tuple(a.reshape(-1, GATHER_CHUNK) for a in (block_idx, c, cutoff))
+    return jax.lax.map(call, chunks).reshape(-1)[:B]
 
 
 @functools.partial(
     jax.jit, static_argnames=("bits", "sigma", "queries_per_step", "impl")
 )
 def rank_packed(fused, block_idx, c, cutoff, *, bits: int, sigma: int,
-                queries_per_step: int = 8, impl: str | None = None):
+                queries_per_step: int = 128, impl: str | None = None):
     """Full rank queries (checkpoint base + in-block popcount) over the
     fused packed layout: Occ(c_i, block_idx_i * r + cutoff_i) for each query.
 
@@ -192,29 +219,18 @@ def rank_packed(fused, block_idx, c, cutoff, *, bits: int, sigma: int,
     int32[B] -> int32[B].  ``bits`` in {2, 4} is the packed field width.
     ``impl``: None -> backend default ("pallas" on TPU, "jnp" popcount
     fallback elsewhere); "interpret" runs the kernel in interpret mode for
-    parity testing.  ``queries_per_step`` clamps to the next power of two
-    >= B, so scalar walks (the BWT-merge interleave walk issues one- and
-    two-query dispatches per step) don't pay for 8 grid lanes of work.
+    parity testing.  ``queries_per_step`` (one lane row of answers per
+    outer grid step) clamps as in ``_gather_queries``.
     """
-    impl = _rank_impl_default() if impl is None else impl
+    impl = _impl_default() if impl is None else impl
     if impl == "jnp":
         return rank_packed_jnp(fused, block_idx, c, cutoff,
                                bits=bits, sigma=sigma)
-    B = block_idx.shape[0]
-    queries_per_step = min(
-        queries_per_step, 1 << max(0, B - 1).bit_length()
+    return _gather_queries(
+        functools.partial(rank_packed_pallas, fused, bits=bits, sigma=sigma),
+        block_idx, c, cutoff, queries_per_step,
+        interpret=(impl == "interpret"),
     )
-    pad = (-B) % queries_per_step
-    if pad:
-        z = jnp.zeros(pad, jnp.int32)
-        block_idx, c, cutoff = (
-            jnp.concatenate([a, z]) for a in (block_idx, c, cutoff)
-        )
-    out = rank_packed_pallas(
-        fused, block_idx, c, cutoff, bits=bits, sigma=sigma,
-        queries_per_step=queries_per_step, interpret=(impl == "interpret"),
-    )
-    return out[:B]
 
 
 def rank_walkers(fused, blocks, occ, block_idx, c, cutoff, *, bits: int,
@@ -239,13 +255,16 @@ def rank_walkers(fused, blocks, occ, block_idx, c, cutoff, *, bits: int,
 @functools.partial(jax.jit, static_argnames=("impl",))
 def rank_unpacked(bwt_blocks, block_idx, c, cutoff, *, impl: str | None = None):
     """Batched in-block rank counts over unpacked int32 blocks (the sigma>16
-    layout): same contract as ``rank_select`` (int32[B] queries ->
-    int32[B] counts, NOT including the checkpoint base).  Dispatch:
-    scalar-prefetch Pallas kernel on TPU, vectorised jnp gather elsewhere;
-    "interpret" for parity testing."""
-    impl = _rank_impl_default() if impl is None else impl
+    layout): ``bwt_blocks`` int32[n_blocks, r]; per query i the count of
+    symbol ``c[i]`` in the first ``cutoff[i]`` positions of block
+    ``block_idx[i]`` — int32[B] queries -> int32[B] counts, NOT including
+    the checkpoint base.  Dispatch: scalar-prefetch row-gather Pallas kernel
+    on TPU, vectorised jnp gather elsewhere; "interpret" for parity
+    testing."""
+    impl = _impl_default() if impl is None else impl
     if impl == "jnp":
         return ref.rank_select_ref(bwt_blocks, block_idx, c, cutoff)
-    return rank_select_pallas(
-        bwt_blocks, block_idx, c, cutoff, interpret=(impl == "interpret")
+    return _gather_queries(
+        functools.partial(rank_select_pallas, bwt_blocks),
+        block_idx, c, cutoff, 128, interpret=(impl == "interpret"),
     )

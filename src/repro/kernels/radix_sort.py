@@ -7,9 +7,9 @@ hist -> exclusive-scan -> scatter pipeline, one 8-bit digit per pass:
   1. ``radix_hist_pallas``      per-block 256-bin digit histograms (VMEM)
   2. digit-major exclusive scan (tiny: nblocks x 256, plain jnp)
   3. ``radix_pos_pallas``       per-element destination = global bin base +
-                                stable intra-block rank (onehot cumsum in
-                                VMEM, no gathers — onehot-select only)
-  4. apply                      one XLA scatter per operand
+                                stable intra-block rank (one-hot rows times
+                                a triangular matrix on the MXU, no gathers)
+  4. apply                      one XLA scatter of all operands, stacked
 
 The scatter itself stays in XLA on purpose: Mosaic's block model cannot
 express an arbitrary HBM scatter (an output block must be addressed by the
@@ -36,58 +36,79 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .radix_hist import radix_hist_pallas
-
-
-def _pos_kernel(keys_ref, base_ref, out_ref, *, shift: int, block: int):
-    keys = keys_ref[...].reshape(-1).astype(jnp.uint32)
-    digits = (keys >> shift) & 0xFF                       # (block,)
-    bins = lax.broadcasted_iota(jnp.uint32, (block, 256), 1)
-    onehot = digits[:, None] == bins                      # (block, 256)
-    incl = jnp.cumsum(onehot.astype(jnp.int32), axis=0)   # stable intra rank
-    base = base_ref[...].reshape(-1).astype(jnp.int32)    # (256,) bin bases
-    pos = jnp.sum(jnp.where(onehot, base[None, :] + incl - 1, 0), axis=1)
-    out_ref[...] = pos.astype(jnp.int32).reshape(out_ref.shape)
+from .radix_hist import LANES, block_layout, digit_onehot, radix_hist_pallas
 
 
-def radix_pos_pallas(keys, base, shift: int, *, block: int = 1024,
+def _pos_kernel(shift_ref, keys_ref, base_ref, out_ref, *, rows: int,
+                group: int):
+    shift = shift_ref[0]
+    li = lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0)
+    lj = lax.broadcasted_iota(jnp.int32, (LANES, LANES), 1)
+    upper = (li < lj).astype(jnp.bfloat16)         # upper[l', l] = l' < l
+    base_t = base_ref[...].T                        # (256, group) bin bases
+    col = lax.broadcasted_iota(jnp.int32, base_t.shape, 1)
+
+    def one_block(j, c):
+        r0 = pl.multiple_of(j * rows, rows)
+        tile = keys_ref[pl.ds(r0, rows), :]
+        # running per-bin destination: global bin base + keys of that bin
+        # already placed by earlier rows of this block
+        nxt = jnp.sum(jnp.where(col == j, base_t, 0), axis=1, keepdims=True)
+        out = []
+        for r in range(rows):
+            onehot = digit_onehot(tile[r:r + 1, :], shift)   # (256, 128)
+            # stable intra-row rank: same-bin keys on earlier lanes, via a
+            # strictly-triangular matmul on the MXU (counts <= 127, exact)
+            before = jnp.dot(onehot.astype(jnp.bfloat16), upper,
+                             preferred_element_type=jnp.float32)
+            dest = before.astype(jnp.int32) + nxt
+            out.append(jnp.sum(jnp.where(onehot, dest, 0), axis=0,
+                               keepdims=True))
+            nxt = nxt + jnp.sum(onehot.astype(jnp.int32), axis=1,
+                                keepdims=True)
+        out_ref[pl.ds(r0, rows), :] = jnp.concatenate(out, axis=0)
+        return c
+
+    lax.fori_loop(0, group, one_block, 0)
+
+
+def radix_pos_pallas(keys, base, shift, *, block: int = 1024,
                      interpret: bool = False):
     """Destination position of every element for one 8-bit digit pass.
 
     keys uint32[n] (n % block == 0), base int32[n//block, 256] = global
-    start of (block, digit) runs in digit-major order.
+    start of (block, digit) runs in digit-major order; ``shift`` may be
+    traced.
     """
     n = keys.shape[0]
-    if n % block:
-        raise ValueError(f"n={n} must be a multiple of block={block}")
-    nblocks = n // block
-    lanes = 128
-    rows = block // lanes
-    if block % lanes:
-        raise ValueError(f"block={block} must be a multiple of {lanes}")
-    x2d = keys.reshape(nblocks * rows, lanes)
-    out = pl.pallas_call(
-        functools.partial(_pos_kernel, shift=shift, block=block),
-        grid=(nblocks,),
+    nblocks, rows, group = block_layout(n, block)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(nblocks // group,),
         in_specs=[
-            pl.BlockSpec((rows, lanes), lambda i: (i, 0)),
-            pl.BlockSpec((1, 256), lambda i: (i, 0)),
+            pl.BlockSpec((group * rows, LANES), lambda i, s: (i, 0)),
+            pl.BlockSpec((group, 256), lambda i, s: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((rows, lanes), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nblocks * rows, lanes), jnp.int32),
+        out_specs=pl.BlockSpec((group * rows, LANES), lambda i, s: (i, 0)),
+    )
+    out = pl.pallas_call(
+        functools.partial(_pos_kernel, rows=rows, group=group),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n // LANES, LANES), jnp.int32),
         interpret=interpret,
-    )(x2d, base)
+    )(jnp.asarray(shift, jnp.int32).reshape(1), keys.reshape(-1, LANES), base)
     return out.reshape(n)
 
 
 def _digit_major_bases(hist):
     """(nblocks, 256) per-block histograms -> (nblocks, 256) global bin
-    bases: exclusive scan in (digit, block) order."""
-    nblocks, nbins = hist.shape
-    flat = hist.T.reshape(-1)                   # digit-major
-    starts = jnp.cumsum(flat) - flat
-    return starts.reshape(nbins, nblocks).T.astype(jnp.int32)
+    bases: exclusive scan in (digit, block) order, i.e. all smaller digits
+    plus this digit in earlier blocks."""
+    before = jnp.cumsum(hist, axis=0) - hist
+    totals = jnp.sum(hist, axis=0)
+    return (before + (jnp.cumsum(totals) - totals)[None, :]).astype(jnp.int32)
 
 
 def radix_sort_pallas(operands, num_keys: int, key_bits, *,
@@ -96,19 +117,29 @@ def radix_sort_pallas(operands, num_keys: int, key_bits, *,
 
     ``operands[:num_keys]`` are uint32 key words, most-significant first
     (the ``lax.sort`` convention); ``key_bits[w]`` bounds the significant
-    bits of word w.  n must be a multiple of ``block`` (ops pads).
+    bits of word w.  n must be a multiple of ``GROUP * block`` (ops pads).
+    The passes over one key word run as a ``fori_loop`` with the digit
+    shift traced, so the program holds one pass per key word; all operands
+    move in one scatter of their stacked 32-bit columns.
     """
-    arrs = list(operands)
+    dtypes = [a.dtype for a in operands]
+    cols = jnp.stack([lax.bitcast_convert_type(a, jnp.int32)
+                      for a in operands], axis=1)
+
+    def one_pass(w, p, cols):
+        word = cols[:, w]
+        shift = p * 8
+        hist = radix_hist_pallas(word, shift, block=block,
+                                 interpret=interpret)
+        pos = radix_pos_pallas(word, _digit_major_bases(hist), shift,
+                               block=block, interpret=interpret)
+        return jnp.zeros_like(cols).at[pos].set(cols, unique_indices=True)
+
     for w in range(num_keys - 1, -1, -1):
-        for shift in range(0, key_bits[w], 8):
-            word = arrs[w]
-            hist = radix_hist_pallas(word, shift, block=block,
-                                     interpret=interpret)
-            base = _digit_major_bases(hist)
-            pos = radix_pos_pallas(word, base, shift, block=block,
-                                   interpret=interpret)
-            arrs = [jnp.zeros_like(a).at[pos].set(a) for a in arrs]
-    return tuple(arrs)
+        passes = -(-key_bits[w] // 8)
+        cols = lax.fori_loop(0, passes, functools.partial(one_pass, w), cols)
+    return tuple(lax.bitcast_convert_type(cols[:, i], dt)
+                 for i, dt in enumerate(dtypes))
 
 
 def radix_sort_jnp(operands, num_keys: int, key_bits, *,

@@ -11,16 +11,14 @@ so a single row fetch (one cache line / one DMA) yields both the rank base
 and the block payload — the interleaved-checkpoint struct of classic
 cache-aware FM indexes.
 
-``rank_packed_pallas`` is the fused kernel: a grid step answers
-``queries_per_step`` rank queries against the whole fused array resident in
-VMEM (bit-packing shrinks it 8-16x vs int32 symbols, so corpus-scale shards
-fit), counting matches popcount-style over packed words instead of scanning
-symbols.  ``rank_packed_jnp`` is the same math as a pure-jnp fallback for
-hosts without a TPU (selected at build/dispatch time in ``ops.py``).
+``rank_packed_pallas`` is the fused kernel: the fused array stays in HBM
+and each inner grid step DMAs the one row its scalar-prefetched block index
+selects, then counts matches popcount-style over the packed words instead
+of scanning symbols.  ``rank_packed_jnp`` is the same math as a pure-jnp
+fallback for hosts without a TPU (selected at dispatch time in ``ops.py``).
 
-``rank_select_pallas`` is the legacy one-query-per-grid-step scalar-prefetch
-kernel over *unpacked* int32 blocks; it remains the fallback layout for
-alphabets too large to pack (sigma > 16).
+``rank_select_pallas`` runs the same row gather over *unpacked* int32
+blocks, the layout of alphabets too large to pack (sigma > 16).
 """
 
 from __future__ import annotations
@@ -101,101 +99,100 @@ def rank_packed_jnp(fused, block_idx, c, cutoff, *, bits: int, sigma: int):
     return (base + cnt.astype(jnp.int32)).astype(jnp.int32)
 
 
-def _packed_kernel(bidx_ref, c_ref, cut_ref, fused_ref, out_ref,
-                   *, bits: int, sigma: int, queries_per_step: int):
-    i = pl.program_id(0)
-    wid = fused_ref.shape[1]
-    W = wid - sigma
-    rep = jnp.uint32(_REP[bits])
-
-    def body(q, acc):
-        g = i * queries_per_step + q
-        blk = bidx_ref[g]
-        c = c_ref[g]
-        cut = cut_ref[g]
-        row = fused_ref[pl.ds(blk, 1), :]                    # (1, sigma+W)
-        base = lax.dynamic_slice(row, (0, c), (1, 1))[0, 0]
-        w = lax.bitcast_convert_type(
-            lax.slice(row, (0, sigma), (1, wid)), jnp.uint32
-        )                                                    # (1, W)
-        eq = _eq_fields(w ^ c.astype(jnp.uint32) * rep, bits)
-        wi = lax.broadcasted_iota(jnp.int32, (1, W), 1)
-        sel = _cutoff_mask(wi, cut, bits)
-        cnt = jnp.sum(lax.population_count(eq & sel)).astype(jnp.int32)
-        return acc.at[q].set(base + cnt)
-
-    out_ref[:] = lax.fori_loop(
-        0, queries_per_step, body,
-        jnp.zeros((queries_per_step,), jnp.int32),
-    )
+def _packed_row_count(row, c, cut, *, bits: int, sigma: int):
+    """Occ checkpoint + in-block popcount of one fused row (1, sigma + W):
+    the base is a one-hot masked sum over the sigma checkpoint lanes (no
+    dynamic lane slice), the count an XOR + popcount over the word lanes."""
+    lane = lax.broadcasted_iota(jnp.int32, row.shape, 1)
+    base = jnp.sum(jnp.where(lane == c, row, 0))
+    w = lax.bitcast_convert_type(row, jnp.uint32)
+    eq = _eq_fields(w ^ c.astype(jnp.uint32) * jnp.uint32(_REP[bits]), bits)
+    wi = lane - sigma
+    sel = jnp.where(wi >= 0, _cutoff_mask(wi, cut, bits), jnp.uint32(0))
+    return base + jnp.sum(lax.population_count(eq & sel).astype(jnp.int32))
 
 
-def rank_packed_pallas(fused, block_idx, c, cutoff, *, bits: int, sigma: int,
-                       queries_per_step: int = 8, interpret: bool = False):
-    """Fused multi-query rank kernel over the packed layout.
+def _unpacked_row_count(row, c, cut):
+    """Count of symbol c among the first ``cut`` entries of one int32 row."""
+    pos = lax.broadcasted_iota(jnp.int32, row.shape, 1)
+    return jnp.sum(((row == c) & (pos < cut)).astype(jnp.int32))
 
-    The whole fused array lives in VMEM (packing makes it small); every grid
-    step answers ``queries_per_step`` queries, each gathering one fused row
-    (checkpoint base + packed words in a single access) and counting matches
-    via XOR + popcount.  B must be a multiple of queries_per_step (ops.py
-    pads).
+
+def _gather_kernel(bidx_ref, c_ref, cut_ref, row_ref, out_ref, *,
+                   row_count, queries_per_step: int):
+    i, j = pl.program_id(0), pl.program_id(1)
+    g = i * queries_per_step + j
+    val = row_count(row_ref[...], c_ref[g], cut_ref[g])
+
+    @pl.when(j == 0)
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    lane = lax.broadcasted_iota(jnp.int32, out_ref.shape, 1)
+    out_ref[...] = jnp.where(lane == j, val, out_ref[...])
+
+
+def _row_gather_call(row_count, rows, block_idx, c, cutoff, *,
+                     queries_per_step: int, interpret: bool):
+    """One rank query per inner grid step over an HBM-resident row table.
+
+    ``rows`` int32[nb, w] stays in HBM; the scalar-prefetched ``block_idx``
+    drives the input index map, so each step DMAs only the selected row,
+    viewed as a (1, w) block of the 3-D (nb, 1, w) array (its last two dims
+    equal the array's, which the TPU tiling rule admits for any w).  Each
+    outer step writes one lane-aligned (1, Q) output row.  B must be a
+    multiple of ``queries_per_step`` (ops.py pads).
     """
     B = block_idx.shape[0]
     Q = queries_per_step
     assert B % Q == 0, (B, Q)
-    nb, wid = fused.shape
+    nb, wid = rows.shape
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B // Q,),
-        in_specs=[pl.BlockSpec((nb, wid), lambda i, b, c, t: (0, 0))],
-        out_specs=pl.BlockSpec((Q,), lambda i, b, c, t: (i,)),
+        grid=(B // Q, Q),
+        in_specs=[pl.BlockSpec((None, 1, wid),
+                               lambda i, j, b, c, t: (b[i * Q + j], 0, 0))],
+        out_specs=pl.BlockSpec((None, 1, Q), lambda i, j, b, c, t: (i, 0, 0)),
     )
-    kernel = functools.partial(
-        _packed_kernel, bits=bits, sigma=sigma, queries_per_step=Q
-    )
-    return pl.pallas_call(
-        kernel,
+    out = pl.pallas_call(
+        functools.partial(_gather_kernel, row_count=row_count,
+                          queries_per_step=Q),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B,), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((B // Q, 1, Q), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(block_idx, c, cutoff, fused)
+    )(block_idx, c, cutoff, rows.reshape(nb, 1, wid))
+    return out.reshape(B)
+
+
+def rank_packed_pallas(fused, block_idx, c, cutoff, *, bits: int, sigma: int,
+                       queries_per_step: int = 128, interpret: bool = False):
+    """Fused rank kernel over the packed layout: Occ checkpoint base +
+    XOR/popcount in-block count, one fused row fetched per query.
+
+    The fused array stays in HBM (a genome-scale index does not fit VMEM);
+    B must be a multiple of ``queries_per_step`` (ops.py pads).
+    """
+    row_count = functools.partial(_packed_row_count, bits=bits, sigma=sigma)
+    return _row_gather_call(row_count, fused, block_idx, c, cutoff,
+                            queries_per_step=queries_per_step,
+                            interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
-# legacy unpacked path (sigma > 16): one query per grid step, scalar-prefetch
-# DMA of the selected int32 block.
+# unpacked path (sigma > 16): the same row gather over int32 symbol blocks
 # ---------------------------------------------------------------------------
 
 
-def _kernel(block_idx_ref, c_ref, cutoff_ref, bwt_ref, out_ref):
-    q = pl.program_id(0)
-    c = c_ref[q]
-    cutoff = cutoff_ref[q]
-    blk = bwt_ref[0, :]
-    pos = jnp.arange(blk.shape[0], dtype=jnp.int32)
-    out_ref[0] = jnp.sum((blk == c) & (pos < cutoff)).astype(jnp.int32)
-
-
-def rank_select_pallas(bwt_blocks, block_idx, c, cutoff, *, interpret=False):
+def rank_select_pallas(bwt_blocks, block_idx, c, cutoff, *,
+                       queries_per_step: int = 128, interpret=False):
     """In-block counts for FM rank queries over unpacked int32 blocks.
 
-    bwt_blocks int32[nblocks, r]; block_idx/c/cutoff int32[B].
-    Returns int32[B]: count of c among the first ``cutoff`` entries of the
-    selected block, one query per grid step.
+    bwt_blocks int32[nblocks, r]; block_idx/c/cutoff int32[B] with B a
+    multiple of ``queries_per_step``.  Returns int32[B]: count of c among
+    the first ``cutoff`` entries of the selected block.
     """
-    B = block_idx.shape[0]
-    r = bwt_blocks.shape[1]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, r), lambda q, bidx, c, cut: (bidx[q], 0)),
-        ],
-        out_specs=pl.BlockSpec((1,), lambda q, bidx, c, cut: (q,)),
-    )
-    return pl.pallas_call(
-        _kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B,), jnp.int32),
-        interpret=interpret,
-    )(block_idx, c, cutoff, bwt_blocks)
+    return _row_gather_call(_unpacked_row_count, bwt_blocks, block_idx, c,
+                            cutoff, queries_per_step=queries_per_step,
+                            interpret=interpret)

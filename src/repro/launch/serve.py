@@ -33,9 +33,14 @@ import jax
 
 def main(argv=None):
     from ..configs.bwt_index import CONFIG as icfg
+    from . import compile_cache
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--kind", default="dna")
+    ap.add_argument("--chips", type=int, default=1,
+                    help="devices to shard the build and queries over (a "
+                         "1-D 'parts' mesh over the first N devices); 1 = "
+                         "the single-device index")
     ap.add_argument("--n", type=int, default=1 << 16)
     ap.add_argument("--batch", type=int, default=256)
     ap.add_argument("--batches", type=int, default=10)
@@ -80,6 +85,10 @@ def main(argv=None):
                          "recovery paths instead of the happy path; a "
                          "fault report prints on exit")
     args = ap.parse_args(argv)
+    compile_cache.enable()
+    if not 1 <= args.chips <= len(jax.devices()):
+        ap.error(f"--chips {args.chips}: {len(jax.devices())} devices "
+                 "visible")
     if args.segments > args.n:
         ap.error(f"--segments {args.segments} exceeds --n {args.n} "
                  "(every segment needs at least one token)")
@@ -102,8 +111,10 @@ def main(argv=None):
     from ..core.segments import SegmentedIndex
     from ..data.corpus import corpus
 
-    ndev = len(jax.devices())
-    mesh = jax.make_mesh((ndev,), ("parts",)) if ndev > 1 else None
+    mesh = None
+    if args.chips > 1:
+        mesh = jax.make_mesh((args.chips,), ("parts",),
+                             devices=jax.devices()[: args.chips])
 
     def load_tokens(path):
         if path.endswith(".npz"):
@@ -242,8 +253,13 @@ def main(argv=None):
                         and rng.random() < args.locate_frac else "count")
                 futs.append(fe.submit(sample(sources), kind))
             hits = shed = 0
+            errors = []
             for f in futs:
-                r = f.result()
+                try:
+                    r = f.result()
+                except Exception as e:  # noqa: BLE001 — counted, fails run
+                    errors.append(repr(e))
+                    continue
                 if isinstance(r, Rejected):
                     shed += 1
                 else:
@@ -259,6 +275,16 @@ def main(argv=None):
         )
         if faultinject.active() is not None:
             print(f"fault report: {faultinject.active().report()}")
+            return
+        # with no faults injected, a recovery path taken is a failed run:
+        # the frontend keeps serving through it, the exit code must not
+        failed = {"compaction quarantined": m["quarantined_segments"],
+                  "worker restarts": m["worker_restarts"],
+                  "failed requests": len(errors)}
+        if any(failed.values()):
+            for e in errors[:5]:
+                print(f"request failed: {e}")
+            raise SystemExit(f"serve failed: {failed}")
         return
 
     lats = []

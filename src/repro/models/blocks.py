@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
+from jax import shard_map
 from ..configs.base import ArchConfig
 from ..sharding import MeshContext, constrain
 from .common import ParamSpec, apply_rope, dense, rms_norm

@@ -147,6 +147,8 @@ class FMQueryServer:
         request only."""
         if kind not in ("count", "locate"):
             raise ValueError(f"unknown query kind {kind!r}")
+        if kind == "locate" and k is not None and k < 1:
+            raise ValueError(f"locate needs k >= 1, got {k}")
         t = self._next_ticket
         self._next_ticket += 1
         self._queue.append(

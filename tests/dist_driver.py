@@ -9,6 +9,9 @@ import os
 import sys
 
 DEVICES = int(sys.argv[2]) if len(sys.argv) > 2 else 8
+# forced host devices live on the CPU backend; never reach for a chip that
+# the parent process may hold
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     f"--xla_force_host_platform_device_count={DEVICES} "
     + os.environ.get("XLA_FLAGS", "")
@@ -20,7 +23,7 @@ import numpy as np  # noqa: E402
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-from repro.compat import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 from repro.core import alphabet as al  # noqa: E402
 from repro.core.dist_sort import (  # noqa: E402
     ShardInfo,
@@ -93,14 +96,16 @@ def scenario_shift():
     n = DEVICES * 16
     info = ShardInfo(AXIS, DEVICES, n // DEVICES)
     x = rng.integers(0, 100, n).astype(np.int32)
-    for h in [1, 2, 3, 15, 16, 17, 64, n - 1]:
-        def f(a):
-            return shift_sharded(info, a, h, -1)
-
-        out = np.asarray(shard_call(mesh, f, jnp.asarray(x)))
+    for h in [1, 2, 3, 15, 16, 17, 64, n - 1, n + 5]:
         want = np.full(n, -1, np.int32)
-        want[: n - h] = x[h:]
-        assert np.array_equal(out, want), f"shift h={h}"
+        want[: max(0, n - h)] = x[h:]
+        # a static distance, and the same distance traced
+        for dist in (h, jnp.int32(h)):
+            def f(a):
+                return shift_sharded(info, a, dist, -1)
+
+            out = np.asarray(shard_call(mesh, f, jnp.asarray(x)))
+            assert np.array_equal(out, want), f"shift h={dist!r}"
     print("shift ok")
 
 

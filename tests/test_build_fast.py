@@ -16,6 +16,7 @@ from repro.core.suffix_array import (
     OVERFLOW_RANK,
     build_isa_fast,
     isa_prefix_doubling,
+    prefix_max,
     sa_from_isa,
     suffix_array_fast,
 )
@@ -33,6 +34,17 @@ def _corpus(sigma_hi: int, n: int, seed: int = 0) -> np.ndarray:
     else:
         toks = rng.integers(1, sigma_hi, n - 1).astype(np.int32)
     return al.append_sentinel(toks)
+
+
+@pytest.mark.parametrize("n,width", [(1, 4), (7, 8), (8, 8), (9, 8),
+                                     (1000, 16), (5000, 1024)])
+def test_prefix_max_matches_running_max(n, width):
+    """The blocked running max equals numpy's flat one, including the
+    -1 'no head yet' sentinels the build passes in."""
+    rng = np.random.default_rng(n)
+    x = rng.integers(-1, 10 * n, n).astype(np.int32)
+    got = np.asarray(prefix_max(jnp.asarray(x), width))
+    assert np.array_equal(got, np.maximum.accumulate(x))
 
 
 class TestKeypack:

@@ -537,3 +537,30 @@ class TestFrontendAppend:
             np.asarray(extra[100:110], np.int32)[None, :]
         )[0]
         assert got >= want >= 1
+
+    def test_serve_launcher_fails_on_quarantined_compaction(
+            self, tmp_path, monkeypatch):
+        """With no fault schedule armed, a recovery path taken while
+        serving is a failed run: the frontend quarantines the poison
+        compaction and keeps answering, but the launcher exits non-zero."""
+        from repro.launch import serve as serve_launcher
+
+        ckpt = str(tmp_path / "cat")
+        extra_path = str(tmp_path / "extra.npy")
+        np.save(extra_path,
+                np.random.default_rng(4).integers(1, 5, 512).astype(np.int32))
+        serve_launcher.main([
+            "--kind", "dna", "--n", "2048", "--segments", "2",
+            "--batch", "4", "--batches", "1", "--ckpt-dir", ckpt,
+        ])
+
+        def poison(self):
+            raise RuntimeError("compaction blew up")
+
+        monkeypatch.setattr(SegmentedIndex, "maybe_compact", poison)
+        with pytest.raises(SystemExit, match="compaction quarantined"):
+            serve_launcher.main([
+                "--restore", "--ckpt-dir", ckpt, "--append", extra_path,
+                "--serve-async", "--batch", "4", "--batches", "2",
+                "--queue-depth", "128",
+            ])
