@@ -196,7 +196,8 @@ def _merge_walk(fusedA, blocksA, occA, cA, nbA, rowA, lastA,
         r_new = lf_all[rr]
         return I_new, r_new, ins.at[r_new].set(I_new)
 
-    _, _, ins = lax.fori_loop(0, nB - 1, body, (I0, r0, ins0))
+    with jax.named_scope("merge_walk"):
+        _, _, ins = lax.fori_loop(0, nB - 1, body, (I0, r0, ins0))
     return ins
 
 
@@ -447,9 +448,10 @@ def _kway_walk(fusedS, blocksS, occS, c_mat, nb_vec, row_vec, last_vec,
         return I_new, seg_new, pos_new, record(ins, seg_new, I_new)
 
     n_walk = jnp.where(active & (lanes >= 1), n_vec, 0).sum()
-    _, _, _, ins = lax.fori_loop(
-        0, n_walk - 1, body, (I0, seg0, pos0, ins0)
-    )
+    with jax.named_scope("kway_walk"):
+        _, _, _, ins = lax.fori_loop(
+            0, n_walk - 1, body, (I0, seg0, pos0, ins0)
+        )
     return ins
 
 

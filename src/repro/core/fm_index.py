@@ -29,6 +29,12 @@ rank(c, p) = occ_samples[p // r, c] + count of c in bwt[(p//r)*r : p].
 FM-index trade-off the paper cites ([4] Ferragina-Manzini).  The in-block
 count is the hot spot; all query paths dispatch through ``kernels/ops``
 (packed popcount Pallas kernel on TPU, vectorised jnp fallback elsewhere).
+
+``build_sa_samples`` opens the profiler span ``fm.build.sa_samples``
+(``rows`` marked), with an ``fm.fetch`` child around the copy of the suffix
+array to the host.  The query programs carry named scopes
+(``backward_search``, ``locate_walk``, ``sample_lookup``) that label their
+operations in a device trace.
 """
 
 from __future__ import annotations
@@ -40,6 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.profiler import TraceAnnotation
 
 from ..kernels import ops
 from ..kernels.rank_select import pack_words, packed_bits
@@ -180,13 +187,17 @@ def build_sa_samples(sa, sa_sample_rate: int, *, compress: bool | None = None):
     (default) packs whenever that width beats raw int32; the returned
     ``val_bits`` (0 = raw) selects the decode in ``sample_lookup``.
     """
-    sa_np = np.asarray(sa)
-    marked = (sa_np % sa_sample_rate) == 0
-    # SA holds 0, so the marked set is never empty
-    return sample_arrays_from_rows(
-        np.nonzero(marked)[0], sa_np[marked].astype(np.int32),
-        sa_np.shape[0], sa_sample_rate, compress=compress,
-    )
+    with TraceAnnotation("fm.build.sa_samples") as span:
+        with TraceAnnotation("fm.fetch"):
+            sa_np = np.asarray(sa)
+        marked = (sa_np % sa_sample_rate) == 0
+        rows = np.nonzero(marked)[0]
+        span.set_metadata(rows=len(rows))
+        # SA holds 0, so the marked set is never empty
+        return sample_arrays_from_rows(
+            rows, sa_np[marked].astype(np.int32),
+            sa_np.shape[0], sa_sample_rate, compress=compress,
+        )
 
 
 def decode_sa_values(fm) -> np.ndarray:
@@ -336,6 +347,7 @@ def _interval_step(c, sp, ep, sigma: int, rank):
     )
 
 
+@jax.named_scope("backward_search")
 def backward_search_batch(
     index: FMIndex, patterns: jax.Array
 ) -> tuple[jax.Array, jax.Array]:
@@ -376,6 +388,7 @@ def count(index: FMIndex, patterns: jax.Array) -> jax.Array:
     return jnp.maximum(ep - sp, 0)
 
 
+@jax.named_scope("sample_lookup")
 def sample_lookup(marks, mark_ranks, vals, rows, *, val_bits: int = 0,
                   val_scale: int = 1, idx_offset=0):
     """(marked, value) of the SA sample at each row (value garbage when
@@ -431,6 +444,7 @@ def bwt_symbol(index: FMIndex, rows: jax.Array) -> jax.Array:
                          sigma=index.sigma, bits=index.bits)
 
 
+@jax.named_scope("locate_walk")
 def _locate_walk(n_steps: int, rows, valid, lookup, lf_next):
     """The locate LF-walk, shared by the monolithic and stacked paths —
     any divergence here would break their bit-identity.  Each lane walks
@@ -849,6 +863,7 @@ def _stacked_occ_batch(st: StackedFMIndex, seg, c, p):
     return base + ops.rank_unpacked(st.blocks, row, c, cut)
 
 
+@jax.named_scope("backward_search")
 def _stacked_backward_search(st: StackedFMIndex, patterns: jax.Array):
     """(sp, ep) int32[S, B]: every pattern against every segment, two rank
     dispatches per scan step (``_interval_step`` — the exact transition of

@@ -9,6 +9,13 @@ unique smallest sentinel first (required by the BWT), then pad with a
 dedicated token HIGHER than every real token.  Pad suffixes consist only of
 pad tokens, so they can never match a query over the real alphabet, and real
 char ranks are unaffected — counting semantics are exact (asserted by tests).
+
+Profiler spans (host code; inert unless a trace is being recorded):
+``fm.build`` around ``build_index`` (``n`` raw tokens, ``sigma``),
+``fm.build.prepare`` around ``prepare_tokens``, ``fm.build.bwt`` around the
+BWT step and ``fm.build.fm`` around the FM step; the suffix sort and the SA
+sampling open their own (``suffix_array.build_isa_fast``,
+``fm_index.build_sa_samples``).
 """
 
 from __future__ import annotations
@@ -18,6 +25,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from . import alphabet as al
@@ -91,20 +99,21 @@ def prepare_tokens(
     the 4-bit packed layout; pass ``reserve_pad=False`` to opt out when the
     index will never be stacked.
     """
-    s = al.append_sentinel(np.asarray(tokens, dtype=np.int32))
-    data_sigma = al.sigma_of(s)
-    declared = sigma is not None
-    if declared and sigma < data_sigma:
-        raise ValueError(f"tokens exceed declared alphabet {sigma}")
-    if reserve_pad is None:
-        reserve_pad = declared
-    sigma = max(data_sigma, sigma or 0)
-    pad = (-len(s)) % multiple
-    if pad:
-        s = np.concatenate([s, np.full(pad, sigma, np.int32)])
-    if pad or reserve_pad:
-        sigma += 1
-    return s, sigma
+    with TraceAnnotation("fm.build.prepare"):
+        s = al.append_sentinel(np.asarray(tokens, dtype=np.int32))
+        data_sigma = al.sigma_of(s)
+        declared = sigma is not None
+        if declared and sigma < data_sigma:
+            raise ValueError(f"tokens exceed declared alphabet {sigma}")
+        if reserve_pad is None:
+            reserve_pad = declared
+        sigma = max(data_sigma, sigma or 0)
+        pad = (-len(s)) % multiple
+        if pad:
+            s = np.concatenate([s, np.full(pad, sigma, np.int32)])
+        if pad or reserve_pad:
+            sigma += 1
+        return s, sigma
 
 
 def build_index_prepared(
@@ -139,11 +148,13 @@ def build_index_prepared(
         )
     else:
         sa, stats = suffix_array(s_dev, sigma), None
-    bwt_arr, row = bwt_from_sa(s_dev, sa)
+    with TraceAnnotation("fm.build.bwt"):
+        bwt_arr, row = bwt_from_sa(s_dev, sa)
     sa_kw = dict(sa_sample_rate=sa_sample_rate) if sa_sample_rate else {}
-    fm = build_fm_index(bwt_arr, row, sigma, sample_rate, pack=pack,
-                        compress_sa=compress_sa,
-                        sa=sa if sa_sample_rate else None, **sa_kw)
+    with TraceAnnotation("fm.build.fm"):
+        fm = build_fm_index(bwt_arr, row, sigma, sample_rate, pack=pack,
+                            compress_sa=compress_sa,
+                            sa=sa if sa_sample_rate else None, **sa_kw)
     n = int(s_dev.shape[0])
     return SequenceIndex(fm, sa, bwt_arr, row, sigma, n,
                          n if text_length is None else text_length,
@@ -185,38 +196,41 @@ def build_index(
     the explicit analogue of Spark skew recovery (DESIGN.md §4).
     """
     tokens = np.asarray(tokens, dtype=np.int32)
-    text_length = len(tokens) + 1
-    sa_kw = dict(sa_sample_rate=sa_sample_rate) if sa_sample_rate else {}
+    with TraceAnnotation("fm.build", n=len(tokens)) as span:
+        text_length = len(tokens) + 1
+        sa_kw = dict(sa_sample_rate=sa_sample_rate) if sa_sample_rate else {}
 
-    if mesh is None:
-        s, sigma = prepare_tokens(tokens, sample_rate, sigma, reserve_pad)
-        return build_index_prepared(
-            s, sigma, sample_rate=sample_rate, sa_config=sa_config,
-            sa_sample_rate=sa_sample_rate, pack=pack, fast=fast,
-            compress_sa=compress_sa, text_length=text_length,
-        )
+        if mesh is None:
+            s, sigma = prepare_tokens(tokens, sample_rate, sigma, reserve_pad)
+            span.set_metadata(sigma=sigma)
+            return build_index_prepared(
+                s, sigma, sample_rate=sample_rate, sa_config=sa_config,
+                sa_sample_rate=sa_sample_rate, pack=pack, fast=fast,
+                compress_sa=compress_sa, text_length=text_length,
+            )
 
-    parts = mesh.shape[sa_config.axis]
-    s, sigma = prepare_tokens(tokens, parts * sample_rate, sigma,
-                              reserve_pad)
-    # shard the text straight from the host: no device holds all of it
-    s_dev = jax.device_put(
-        s, NamedSharding(mesh, PartitionSpec(sa_config.axis)))
-    cfg = sa_config
-    for attempt in range(max_retries):
-        isa = build_isa_sharded(s_dev, mesh, cfg, sigma=sigma)
-        if not isa_overflowed(isa):
-            break
-        cfg = cfg._replace(capacity_factor=cfg.capacity_factor * 2)
-    else:
-        raise RuntimeError(
-            f"samplesort capacity overflow after {max_retries} retries "
-            f"(factor {cfg.capacity_factor})"
-        )
-    sa, bwt_arr, row = _bwt_jit(s_dev, isa, cfg, parts, mesh)
-    fm = build_dist_fm_index(bwt_arr, row, mesh, sigma=sigma,
-                             sample_rate=sample_rate, pack=pack,
-                             compress_sa=compress_sa,
-                             sa=sa if sa_sample_rate else None, **sa_kw)
-    return SequenceIndex(fm, sa, bwt_arr, row, sigma, len(s), text_length,
-                         mesh=mesh)
+        parts = mesh.shape[sa_config.axis]
+        s, sigma = prepare_tokens(tokens, parts * sample_rate, sigma,
+                                  reserve_pad)
+        span.set_metadata(sigma=sigma)
+        # shard the text straight from the host: no device holds all of it
+        s_dev = jax.device_put(
+            s, NamedSharding(mesh, PartitionSpec(sa_config.axis)))
+        cfg = sa_config
+        for attempt in range(max_retries):
+            isa = build_isa_sharded(s_dev, mesh, cfg, sigma=sigma)
+            if not isa_overflowed(isa):
+                break
+            cfg = cfg._replace(capacity_factor=cfg.capacity_factor * 2)
+        else:
+            raise RuntimeError(
+                f"samplesort capacity overflow after {max_retries} retries "
+                f"(factor {cfg.capacity_factor})"
+            )
+        sa, bwt_arr, row = _bwt_jit(s_dev, isa, cfg, parts, mesh)
+        fm = build_dist_fm_index(bwt_arr, row, mesh, sigma=sigma,
+                                 sample_rate=sample_rate, pack=pack,
+                                 compress_sa=compress_sa,
+                                 sa=sa if sa_sample_rate else None, **sa_kw)
+        return SequenceIndex(fm, sa, bwt_arr, row, sigma, len(s), text_length,
+                             mesh=mesh)

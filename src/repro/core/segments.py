@@ -42,6 +42,9 @@ All segments share one declared alphabet (``sigma``), so every segment's
 pad token sorts above every real token of *any* segment and a query over
 the global alphabet can never match padding (see
 ``pipeline.prepare_tokens``).
+
+``count`` and ``locate`` open an ``fm.fetch`` profiler span around each
+wait for and copy of device results.
 """
 
 from __future__ import annotations
@@ -54,6 +57,7 @@ import os
 import warnings
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .bwt_merge import (
     context_order_safe,
@@ -656,11 +660,15 @@ class SegmentedIndex:
         patterns = np.asarray(patterns, np.int32)
         st = self._stacked()
         if st is not None:
-            per = np.asarray(count_stacked(st, patterns), np.int64)
+            per = count_stacked(st, patterns)
+            with TraceAnnotation("fm.fetch"):
+                per = np.asarray(per, np.int64)
             return per[: int(st.n_seg)].sum(axis=0)
         total = np.zeros(patterns.shape[0], np.int64)
         for seg in self.segments:
-            total += np.asarray(seg.index.count(patterns), np.int64)
+            counts = seg.index.count(patterns)
+            with TraceAnnotation("fm.fetch"):
+                total += np.asarray(counts, np.int64)
         return total
 
     def _to_global(self, seg: Segment, pos: np.ndarray, used: np.ndarray,
@@ -700,17 +708,17 @@ class SegmentedIndex:
         st = self._stacked()
         if st is not None:
             pos_all, cnt_all = locate_stacked(st, patterns, k)
-            pos_all = np.asarray(pos_all, np.int64)
-            cnt_all = np.asarray(cnt_all, np.int64)
-            per_seg = (
-                (pos_all[i], cnt_all[i]) for i in range(int(st.n_seg))
-            )
+            with TraceAnnotation("fm.fetch"):
+                pos_all = np.asarray(pos_all, np.int64)
+                cnt_all = np.asarray(cnt_all, np.int64)
+            per_seg = [(pos_all[i], cnt_all[i]) for i in range(int(st.n_seg))]
         else:
-            per_seg = (
-                tuple(np.asarray(a, np.int64)
-                      for a in seg.index.locate(patterns, k))
-                for seg in self.segments
-            )
+            per_seg = []
+            for seg in self.segments:
+                pos, cnt = seg.index.locate(patterns, k)
+                with TraceAnnotation("fm.fetch"):
+                    per_seg.append((np.asarray(pos, np.int64),
+                                    np.asarray(cnt, np.int64)))
         B = patterns.shape[0]
         fill = self.coord_end
         cand = [np.full((B, 1), fill, np.int64)]

@@ -48,6 +48,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.profiler import TraceAnnotation
 
 from . import keypack
 from ..kernels import ops as kernel_ops
@@ -170,7 +171,12 @@ from ..kernels.ops import (  # noqa: E402  (re-export)
 
 @dataclasses.dataclass
 class BuildStats:
-    """Machine-readable build trajectory (feeds BENCH_build.json)."""
+    """Machine-readable build trajectory of ``build_isa_fast``.
+
+    The benchmark drivers (``bench/drivers``) print it in their set-up
+    log, ``chip_smoke.py`` reads ``local_sort``, and the CPU build table
+    ``benchmarks/table2_bwt.py`` writes it out; a profiler trace carries
+    the same rounds as ``fm.build.round`` spans."""
 
     n: int
     sigma: int
@@ -301,43 +307,52 @@ def build_isa_fast(
     Host-driven round loop (reads back the active count each round to pick
     the next capacity bucket); bit-for-bit identical to
     ``isa_prefix_doubling``.  Returns ``(isa, BuildStats)``.
+
+    Profiler spans: ``fm.build.init`` around the init and the readback of
+    its active set; ``fm.build.round`` around each round up to the readback
+    of its active count (``round``, ``h``, ``cap``, ``n_active``: the
+    round's useful lanes are ``n_active`` of ``cap``).
     """
     s = jnp.asarray(s, jnp.int32)
     n = s.shape[0]
     engine = resolve_local_sort(local_sort)
-    if qgram and n > 1:
-        q, fpw, bits = keypack.qgram_params(sigma, qgram_words)
-        rank, active = _qgram_init(s, fpw, bits, qgram_words, engine)
-        h = q
-        skipped = keypack.qgram_rounds_skipped(q)
-    else:
-        q, h, skipped = 1, 1, 0
-        rank, active = _occ_init(s, sigma)
-    stats = BuildStats(n=n, sigma=sigma, q=q, h0=h, rounds_skipped=skipped,
-                       local_sort=engine, discard=discard)
-    if n <= 1:
-        return rank, stats
+    with TraceAnnotation("fm.build.init"):
+        if qgram and n > 1:
+            q, fpw, bits = keypack.qgram_params(sigma, qgram_words)
+            rank, active = _qgram_init(s, fpw, bits, qgram_words, engine)
+            h = q
+            skipped = keypack.qgram_rounds_skipped(q)
+        else:
+            q, h, skipped = 1, 1, 0
+            rank, active = _occ_init(s, sigma)
+        stats = BuildStats(n=n, sigma=sigma, q=q, h0=h,
+                           rounds_skipped=skipped, local_sort=engine,
+                           discard=discard)
+        if n <= 1:
+            return rank, stats
 
-    if discard:
-        (active_pos,) = jnp.nonzero(active, size=n, fill_value=n)
-        n_active = int(jnp.sum(active))
-        cap = _cap_bucket(n_active, n)
-        active_buf = active_pos[:cap].astype(jnp.int32)
-    else:
-        n_active = n if bool(jnp.any(active)) else 0
-        cap = n
-        active_buf = jnp.arange(n, dtype=jnp.int32)
+        if discard:
+            (active_pos,) = jnp.nonzero(active, size=n, fill_value=n)
+            n_active = int(jnp.sum(active))
+            cap = _cap_bucket(n_active, n)
+            active_buf = active_pos[:cap].astype(jnp.int32)
+        else:
+            n_active = n if bool(jnp.any(active)) else 0
+            cap = n
+            active_buf = jnp.arange(n, dtype=jnp.int32)
 
     while n_active > 0:
         assert h < 2 * n, "prefix doubling failed to converge (bad sentinel?)"
         stats.active_frac.append(n_active / n)
-        step = _fast_round(n, cap, engine)
-        rank, new_buf, n_active_dev = step(
-            rank, active_buf, jnp.int32(n_active), jnp.int32(h)
-        )
+        with TraceAnnotation("fm.build.round", round=stats.rounds_executed,
+                             h=h, cap=cap, n_active=n_active):
+            step = _fast_round(n, cap, engine)
+            rank, new_buf, n_active_dev = step(
+                rank, active_buf, jnp.int32(n_active), jnp.int32(h)
+            )
+            remaining = int(n_active_dev)
         stats.rounds_executed += 1
         h *= 2
-        remaining = int(n_active_dev)
         if discard:
             n_active = remaining
             new_cap = _cap_bucket(n_active, n)

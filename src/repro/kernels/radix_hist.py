@@ -79,4 +79,5 @@ def radix_hist_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nblocks, 256), jnp.int32),
         interpret=interpret,
+        name="radix_hist",
     )(jnp.asarray(shift, jnp.int32).reshape(1), keys.reshape(-1, LANES))

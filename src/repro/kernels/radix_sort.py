@@ -98,6 +98,7 @@ def radix_pos_pallas(keys, base, shift, *, block: int = 1024,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n // LANES, LANES), jnp.int32),
         interpret=interpret,
+        name="radix_pos",
     )(jnp.asarray(shift, jnp.int32).reshape(1), keys.reshape(-1, LANES), base)
     return out.reshape(n)
 
@@ -120,7 +121,9 @@ def radix_sort_pallas(operands, num_keys: int, key_bits, *,
     bits of word w.  n must be a multiple of ``GROUP * block`` (ops pads).
     The passes over one key word run as a ``fori_loop`` with the digit
     shift traced, so the program holds one pass per key word; all operands
-    move in one scatter of their stacked 32-bit columns.
+    move in one scatter of their stacked 32-bit columns.  In a device trace
+    the passes over word ``w`` sit under the named scope ``radix_word<w>``
+    and the scatter under ``scatter``.
     """
     dtypes = [a.dtype for a in operands]
     cols = jnp.stack([lax.bitcast_convert_type(a, jnp.int32)
@@ -133,11 +136,14 @@ def radix_sort_pallas(operands, num_keys: int, key_bits, *,
                                  interpret=interpret)
         pos = radix_pos_pallas(word, _digit_major_bases(hist), shift,
                                block=block, interpret=interpret)
-        return jnp.zeros_like(cols).at[pos].set(cols, unique_indices=True)
+        with jax.named_scope("scatter"):
+            return jnp.zeros_like(cols).at[pos].set(cols, unique_indices=True)
 
     for w in range(num_keys - 1, -1, -1):
         passes = -(-key_bits[w] // 8)
-        cols = lax.fori_loop(0, passes, functools.partial(one_pass, w), cols)
+        with jax.named_scope(f"radix_word{w}"):
+            cols = lax.fori_loop(0, passes, functools.partial(one_pass, w),
+                                 cols)
     return tuple(lax.bitcast_convert_type(cols[:, i], dt)
                  for i, dt in enumerate(dtypes))
 

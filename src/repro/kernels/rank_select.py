@@ -132,9 +132,10 @@ def _gather_kernel(bidx_ref, c_ref, cut_ref, row_ref, out_ref, *,
     out_ref[...] = jnp.where(lane == j, val, out_ref[...])
 
 
-def _row_gather_call(row_count, rows, block_idx, c, cutoff, *,
+def _row_gather_call(row_count, rows, block_idx, c, cutoff, *, name: str,
                      queries_per_step: int, interpret: bool):
-    """One rank query per inner grid step over an HBM-resident row table.
+    """One rank query per inner grid step over an HBM-resident row table;
+    ``name`` is the kernel's name in a device trace.
 
     ``rows`` int32[nb, w] stays in HBM; the scalar-prefetched ``block_idx``
     drives the input index map, so each step DMAs only the selected row,
@@ -162,6 +163,7 @@ def _row_gather_call(row_count, rows, block_idx, c, cutoff, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name=name,
     )(block_idx, c, cutoff, rows.reshape(nb, 1, wid))
     return out.reshape(B)
 
@@ -176,6 +178,7 @@ def rank_packed_pallas(fused, block_idx, c, cutoff, *, bits: int, sigma: int,
     """
     row_count = functools.partial(_packed_row_count, bits=bits, sigma=sigma)
     return _row_gather_call(row_count, fused, block_idx, c, cutoff,
+                            name="rank_gather_packed",
                             queries_per_step=queries_per_step,
                             interpret=interpret)
 
@@ -194,5 +197,6 @@ def rank_select_pallas(bwt_blocks, block_idx, c, cutoff, *,
     the first ``cutoff`` entries of the selected block.
     """
     return _row_gather_call(_unpacked_row_count, bwt_blocks, block_idx, c,
-                            cutoff, queries_per_step=queries_per_step,
+                            cutoff, name="rank_gather_unpacked",
+                            queries_per_step=queries_per_step,
                             interpret=interpret)

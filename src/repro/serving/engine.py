@@ -11,6 +11,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..configs.base import ArchConfig
 from ..models import transformer as tf
@@ -74,6 +75,15 @@ class FMQueryResult:
     positions: np.ndarray | None = None
 
 
+def _fetch(*arrays) -> tuple[np.ndarray, ...]:
+    """Host copies of an index call's results; where the index returned
+    device arrays, the wait for and copy of them is an ``fm.fetch`` span."""
+    if not any(isinstance(a, jax.Array) for a in arrays):
+        return tuple(np.asarray(a) for a in arrays)
+    with TraceAnnotation("fm.fetch"):
+        return tuple(np.asarray(a) for a in arrays)
+
+
 @dataclasses.dataclass
 class FMServerStats:
     queries: int = 0
@@ -95,6 +105,12 @@ class FMQueryServer:
     set of compiled programs no matter what request shapes arrive (the same
     playbook as fixed-shape LM decode buckets).  ``stats`` accumulates a
     tokens/s-style throughput report across flushes.
+
+    Profiler spans: ``fm.flush`` around each flush (``requests``,
+    ``groups``); inside it ``fm.dispatch`` around each padded index call
+    (``kind``, ``length``, ``rows`` = padded batch, ``used`` = real
+    requests), and ``fm.fetch`` around the wait for and copy of results the
+    index returns on the device.
     """
 
     def __init__(self, index, *, length_buckets=(8, 16, 32, 64),
@@ -171,31 +187,37 @@ class FMQueryServer:
         queue, self._queue = self._queue, []
         results: dict[int, FMQueryResult] = {}
         groups: dict[tuple[str, int, int], list[tuple[int, np.ndarray]]] = {}
-        for t, kind, pat, k in queue:
-            key = (kind, self._bucket_len(len(pat)), k if kind == "locate" else 0)
-            groups.setdefault(key, []).append((t, pat))
-        t0 = time.perf_counter()
-        for (kind, L, k), items in sorted(groups.items()):
-            for lo in range(0, len(items), self.max_batch):
-                chunk = items[lo : lo + self.max_batch]
-                B = self._bucket_batch(len(chunk))
-                pats = np.full((B, L), PAD, np.int32)
-                for i, (_, pat) in enumerate(chunk):
-                    pats[i, : len(pat)] = pat
-                if kind == "count":
-                    counts = np.asarray(self.index.count(pats))
-                    for i, (t, _) in enumerate(chunk):
-                        results[t] = FMQueryResult("count", int(counts[i]))
-                else:
-                    pos, counts = self.index.locate(pats, k)
-                    pos, counts = np.asarray(pos), np.asarray(counts)
-                    for i, (t, _) in enumerate(chunk):
-                        c = int(counts[i])
-                        results[t] = FMQueryResult(
-                            "locate", c, pos[i, :c].copy()
-                        )
-                self.stats.batches += 1
-        self.stats.seconds += time.perf_counter() - t0
+        with TraceAnnotation("fm.flush") as span:
+            for t, kind, pat, k in queue:
+                key = (kind, self._bucket_len(len(pat)),
+                       k if kind == "locate" else 0)
+                groups.setdefault(key, []).append((t, pat))
+            span.set_metadata(requests=len(queue), groups=len(groups))
+            t0 = time.perf_counter()
+            for (kind, L, k), items in sorted(groups.items()):
+                for lo in range(0, len(items), self.max_batch):
+                    chunk = items[lo : lo + self.max_batch]
+                    B = self._bucket_batch(len(chunk))
+                    with TraceAnnotation("fm.dispatch", kind=kind, length=L,
+                                         rows=B, used=len(chunk)):
+                        pats = np.full((B, L), PAD, np.int32)
+                        for i, (_, pat) in enumerate(chunk):
+                            pats[i, : len(pat)] = pat
+                        if kind == "count":
+                            (counts,) = _fetch(self.index.count(pats))
+                        else:
+                            pos, counts = _fetch(*self.index.locate(pats, k))
+                    if kind == "count":
+                        for i, (t, _) in enumerate(chunk):
+                            results[t] = FMQueryResult("count", int(counts[i]))
+                    else:
+                        for i, (t, _) in enumerate(chunk):
+                            c = int(counts[i])
+                            results[t] = FMQueryResult(
+                                "locate", c, pos[i, :c].copy()
+                            )
+                    self.stats.batches += 1
+            self.stats.seconds += time.perf_counter() - t0
         self.stats.queries += len(queue)
         self.completed.update(results)
         while len(self.completed) > self.completed_cap:

@@ -49,6 +49,13 @@ Fault model (the robustness substrate the lifecycle makes inevitable):
 * ``stop()`` (alias ``close()``) always resolves every admitted future:
   the worker drains, and anything it never reached — including work
   stranded by a crash during shutdown — is drained inline.
+
+Profiler spans (``jax.profiler.TraceAnnotation``, inert unless a trace is
+being recorded): ``fm.admit`` around each admission, with ``seq``, the
+request's admission number; ``fm.coalesce`` around the worker's wait for
+work; ``fm.batch`` around each flushed batch, with ``n``, ``seq_lo`` and
+``seq_hi`` (the worker takes the whole FIFO queue, so a batch is a
+contiguous range of admission numbers).
 """
 
 from __future__ import annotations
@@ -60,6 +67,7 @@ from collections import deque
 from concurrent.futures import Future, InvalidStateError
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..testing.faultinject import fault_point
 from .engine import FMQueryServer
@@ -130,9 +138,11 @@ class _BucketStats:
         return out
 
 
-# queue entry: (t_enqueue, pattern, kind, k, future, abs_deadline | None)
+# queue entry: (t_enqueue, pattern, kind, k, future, abs_deadline | None,
+# admission number)
 _FUT = 4
 _DEADLINE = 5
+_SEQ = 6
 
 
 class AsyncQueryFrontend:
@@ -267,15 +277,18 @@ class AsyncQueryFrontend:
         pat = np.asarray(pattern, np.int32)
         t0 = time.perf_counter()
         deadline = None if deadline_ms is None else t0 + deadline_ms / 1e3
-        with self._cond:
+        span = TraceAnnotation("fm.admit")
+        with span, self._cond:
             if self._stop:
                 raise RuntimeError("frontend is stopped")
             if len(self._pending) >= self.max_queue:
                 self.rejected += 1
                 fut.set_result(Rejected(kind))
                 return fut
+            seq = self.admitted
+            span.set_metadata(seq=seq)
             self.admitted += 1
-            self._pending.append((t0, pat, kind, k, fut, deadline))
+            self._pending.append((t0, pat, kind, k, fut, deadline, seq))
             self._cond.notify()
         return fut
 
@@ -373,7 +386,8 @@ class AsyncQueryFrontend:
 
     def _run(self) -> None:
         while True:
-            work = self._take_work()
+            with TraceAnnotation("fm.coalesce"):
+                work = self._take_work()
             if work is None:
                 return
             self._inflight = work
@@ -465,6 +479,11 @@ class AsyncQueryFrontend:
                             pass
 
     def _flush_batch(self, batch: list) -> None:
+        with TraceAnnotation("fm.batch", n=len(batch),
+                             seq_lo=batch[0][_SEQ], seq_hi=batch[-1][_SEQ]):
+            self._flush_admitted(batch)
+
+    def _flush_admitted(self, batch: list) -> None:
         # the injected worker-crash site: OUTSIDE every recovery guard, so
         # the exception kills the worker thread and exercises the watchdog
         fault_point("worker.flush")
@@ -493,7 +512,7 @@ class AsyncQueryFrontend:
             # must resolve, if only to an exception
             tickets = [
                 self.server.submit(pat, kind, k=k)
-                for (_, pat, kind, k, _, _) in batch
+                for (_, pat, kind, k, *_) in batch
             ]
             results = self.server.flush()
             outs = [results[t] for t in tickets]
@@ -506,7 +525,7 @@ class AsyncQueryFrontend:
         with self._lock:
             self.flushes += 1
             self.completed += len(batch)
-            for (t0, pat, kind, _, _, _) in batch:
+            for (t0, pat, kind, *_) in batch:
                 self._bucket(kind, len(pat)).record((t_done - t0) * 1e3)
         for out, e in zip(outs, batch):
             e[_FUT].set_result(out)
