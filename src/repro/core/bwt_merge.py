@@ -69,6 +69,7 @@ import numpy as np
 from jax import lax
 
 from ..kernels import ops
+from ..kernels.rank_select import place_rows
 from ..testing.faultinject import fault_point
 from .fm_index import (
     FMIndex,
@@ -128,7 +129,7 @@ def _side_arrays(fm: FMIndex, nb_bucket: int):
     """(fused, blocks, occ) of one side, padded to the block bucket.  Pad
     rows are never addressed (block ids clamp to the true count)."""
     if fm.bits:
-        return _bucket_rows(fm.fused, nb_bucket, 0), None, None
+        return place_rows(_bucket_rows(fm.fused, nb_bucket, 0)), None, None
     r = fm.sample_rate
     blocks = _bucket_rows(fm.bwt.reshape(fm.n_blocks, r), nb_bucket, 0)
     occ = _bucket_rows(fm.occ_samples[:-1], nb_bucket, 0)

@@ -49,7 +49,9 @@ class DistFMIndex:
     occ_samples: jax.Array  # int32[nblocks, sigma] sharded (exclusive, per-shard)
     c_array: jax.Array      # int32[sigma]        replicated
     row: jax.Array          # int32 scalar        replicated
-    fused: jax.Array | None        # int32[nblocks, sigma+W] sharded (packed)
+    fused: jax.Array | None        # int32[nblocks, sigma+W] sharded (packed);
+                                   # 2-D, so the rank kernel views and relays
+                                   # out each shard itself (rank_select.py)
     sa_marks: jax.Array | None     # int32[ceil(n/32)]  replicated
     sa_mark_ranks: jax.Array | None
     sa_vals: jax.Array | None      # raw int32, or packed when sa_val_bits > 0

@@ -10,9 +10,11 @@ Layout (all dense arrays, shard- and jit-friendly):
 * ``bwt``          int32[n_blocks * r]  last column, PAD beyond position n
 * ``C``            int32[sigma]  # chars strictly smaller (exclusive cumsum)
 * ``occ_samples``  int32[n_blocks + 1, sigma]  checkpointed exclusive Occ
-* ``fused``        int32[n_blocks, sigma + r/fpw]  (small alphabets only)
+* ``fused``        int32[n_blocks, 1, sigma + r/fpw]  (small alphabets only)
   per-block [Occ checkpoint | bit-packed words] — the interleaved succinct
-  layout the Pallas rank kernel consumes (kernels/rank_select.py)
+  layout the Pallas rank kernel consumes, stored where it is made in the
+  kernel's own layout (``rank_select.place_rows``), so no query program
+  relays it out
 * ``sa_marks/sa_mark_ranks/sa_vals``  SA sample for locate(): rows whose SA
   value is a multiple of ``sa_sample_rate`` are marked in a bitvector (with
   per-word popcount checkpoints) and their values stored in row order; any
@@ -49,7 +51,7 @@ from jax import lax
 from jax.profiler import TraceAnnotation
 
 from ..kernels import ops
-from ..kernels.rank_select import pack_words, packed_bits
+from ..kernels.rank_select import pack_words, packed_bits, place_rows
 
 PAD = -1  # query padding token
 
@@ -61,7 +63,8 @@ class FMIndex:
     row: jax.Array            # scalar int32: row of the original string
     c_array: jax.Array        # int32[sigma]
     occ_samples: jax.Array    # int32[n_blocks + 1, sigma]
-    fused: jax.Array | None   # int32[n_blocks, sigma + W] packed layout
+    fused: jax.Array | None   # int32[n_blocks, 1, sigma + W] packed layout,
+                              # in the rank kernel's layout (place_rows)
     sa_marks: jax.Array | None       # int32[ceil(n/32)] bitvector
     sa_mark_ranks: jax.Array | None  # int32[ceil(n/32)] excl. popcount cumsum
     sa_vals: jax.Array | None        # int32[#marked] SA values, row order
@@ -284,7 +287,7 @@ def build_fm_index(
     fused = None
     if bits:
         words = pack_words(padded, bits).reshape(n_blocks, -1)
-        fused = jnp.concatenate([occ_samples[:-1], words], axis=1)
+        fused = place_rows(jnp.concatenate([occ_samples[:-1], words], axis=1))
 
     if sa_samples is not None:
         sa_marks, sa_mark_ranks, sa_vals, sa_val_bits = sa_samples
@@ -427,7 +430,7 @@ def packed_symbol(fused, blk, j, *, sigma: int, bits: int) -> jax.Array:
     the one packed-layout decode, shared by the monolithic and stacked
     paths."""
     fpw = 32 // bits
-    word = fused[blk, sigma + j // fpw]
+    word = fused[blk, 0, sigma + j // fpw]
     w = lax.bitcast_convert_type(word, jnp.uint32)
     sh = ((j % fpw) * bits).astype(jnp.uint32)
     return ((w >> sh) & jnp.uint32((1 << bits) - 1)).astype(jnp.int32)
@@ -533,7 +536,8 @@ class StackedFMIndex:
     path serves every segment.
     """
 
-    fused: jax.Array | None    # int32[S*NB, sigma + W]     (packed layout)
+    fused: jax.Array | None    # int32[S*NB, 1, sigma + W]  (packed layout,
+                               # placed as FMIndex.fused)
     blocks: jax.Array | None   # int32[S*NB, r]             (unpacked layout)
     occ: jax.Array | None      # int32[S, NB, sigma]        (unpacked layout)
     c_array: jax.Array         # int32[S, sigma]
@@ -601,11 +605,10 @@ def stack_fm_indexes(
 
     fused = blocks = occ = None
     if bits:
-        W = f0.fused.shape[1]
-        fused_np = np.zeros((S * NB, W), np.int32)
+        fused_np = np.zeros((S * NB,) + f0.fused.shape[1:], np.int32)
         for i, fm in enumerate(fms):
             fused_np[i * NB : i * NB + fm.n_blocks] = np.asarray(fm.fused)
-        fused = jnp.asarray(fused_np)
+        fused = place_rows(jnp.asarray(fused_np))
     else:
         blocks_np = np.full((S * NB, r), PAD, np.int32)
         occ_np = np.zeros((S, NB, sigma), np.int32)
@@ -681,10 +684,10 @@ def stack_rank_arrays(fms: list[FMIndex], *, seg_pad: int | None = None,
         raise ValueError("bucket shape smaller than the run")
     fused = blocks = occ = None
     if bits:
-        fused_np = np.zeros((S * NB, f0.fused.shape[1]), np.int32)
+        fused_np = np.zeros((S * NB,) + f0.fused.shape[1:], np.int32)
         for i, fm in enumerate(fms):
             fused_np[i * NB : i * NB + fm.n_blocks] = np.asarray(fm.fused)
-        fused = jnp.asarray(fused_np)
+        fused = place_rows(jnp.asarray(fused_np))
     else:
         blocks_np = np.full((S * NB, r), PAD, np.int32)
         occ_np = np.zeros((S * NB, sigma), np.int32)
@@ -728,7 +731,7 @@ def _seg_rows(st: StackedFMIndex, fm: FMIndex):
     NB, r, sigma = st.blocks_pad, st.sample_rate, st.sigma
     out = {}
     if st.bits:
-        rows = jnp.zeros((NB, st.fused.shape[1]), jnp.int32)
+        rows = jnp.zeros((NB,) + st.fused.shape[1:], jnp.int32)
         out["fused"] = rows.at[: fm.n_blocks].set(fm.fused)
     else:
         rows = jnp.full((NB, r), PAD, jnp.int32)
@@ -778,7 +781,9 @@ def stacked_append(st: StackedFMIndex, fm: FMIndex) -> StackedFMIndex:
             rep[name] = getattr(st, name).at[i * NB : (i + 1) * NB].set(
                 rows[name]
             )
-    if not st.bits:
+    if st.bits:
+        rep["fused"] = place_rows(rep["fused"])
+    else:
         rep["occ"] = st.occ.at[i].set(rows["occ"])
     rep["c_array"] = st.c_array.at[i].set(rows["c_array"])
     rep["n_blocks"] = st.n_blocks.at[i].set(rows["n_blocks"])
@@ -830,7 +835,7 @@ def stacked_replace_run(st: StackedFMIndex, start: int, count: int,
     rep = {"n_seg": jnp.asarray(n_new, jnp.int32)}
     NB = st.blocks_pad
     if st.bits:
-        rep["fused"] = splice(st.fused, NB, rows["fused"], 0)
+        rep["fused"] = place_rows(splice(st.fused, NB, rows["fused"], 0))
     else:
         rep["blocks"] = splice(st.blocks, NB, rows["blocks"], PAD)
         rep["occ"] = splice(st.occ, 1, rows["occ"][None], 0)

@@ -34,8 +34,10 @@ import json
 import os
 
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh
 
+from ..kernels.rank_select import place_rows
 from ..training.checkpoint import Checkpointer
 from .dist_fm import DistFMIndex, build_dist_fm_index
 from .fm_index import FMIndex, build_fm_index
@@ -115,7 +117,9 @@ def save_index(directory: str, index, *, step: int = 0, keep: int = 3) -> int:
         tree["c_array"] = fm.c_array
         tree["occ_samples"] = fm.occ_samples
         if fm.fused is not None:
-            tree["fused"] = fm.fused
+            # the logical rows, int32[n_blocks, sigma + W]; restore places
+            # them in the rank kernel's layout
+            tree["fused"] = np.asarray(fm.fused).reshape(fm.n_blocks, -1)
     manifest = _manifest(fm, text_length)
     manifest["arrays"] = sorted(tree)
     Checkpointer(directory, keep=keep).save(step, tree, extra=manifest)
@@ -205,7 +209,8 @@ def restore_index(
             fm = FMIndex(
                 jnp.asarray(flat["bwt"]), row, jnp.asarray(flat["c_array"]),
                 jnp.asarray(flat["occ_samples"]),
-                jnp.asarray(flat["fused"]) if "fused" in flat else None,
+                place_rows(jnp.asarray(flat["fused"])) if "fused" in flat
+                else None,
                 *(sa_samples[:3] if sa_samples else (None, None, None)),
                 sample_rate, sigma, meta["length"], meta["bits"],
                 srate, meta["sa_val_bits"],

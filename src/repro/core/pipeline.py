@@ -76,6 +76,11 @@ class SequenceIndex:
             return fm_locate(self.fm, patterns, k)
         return dist_locate(self.fm, patterns, k, self.mesh)
 
+    def packed_row_tables(self) -> tuple:
+        """The packed row tables that ``count`` and ``locate`` read: the
+        index's ``fused`` rows (none for the unpacked layout)."""
+        return () if self.fm.fused is None else (self.fm.fused,)
+
 
 def prepare_tokens(
     tokens: np.ndarray, multiple: int, sigma: int | None = None,

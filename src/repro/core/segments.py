@@ -649,6 +649,15 @@ class SegmentedIndex:
                 self._stacked_cache = False  # unstackable: remember that
         return self._stacked_cache or None
 
+    def packed_row_tables(self) -> tuple:
+        """The packed row tables that ``count`` and ``locate`` read: the
+        stacked catalog's, or each segment's on the sequential path."""
+        st = self._stacked()
+        if st is not None:
+            return () if st.fused is None else (st.fused,)
+        return tuple(t for s in self.segments
+                     for t in s.index.packed_row_tables())
+
     def count(self, patterns) -> np.ndarray:
         """Exact-match counts for int32[B, L] PAD-padded patterns: the sum
         of independent per-segment counts (int64[B]).

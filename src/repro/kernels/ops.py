@@ -214,8 +214,10 @@ def rank_packed(fused, block_idx, c, cutoff, *, bits: int, sigma: int,
     """Full rank queries (checkpoint base + in-block popcount) over the
     fused packed layout: Occ(c_i, block_idx_i * r + cutoff_i) for each query.
 
-    ``fused`` int32[n_blocks, sigma + r*bits/32] rows of
-    [Occ checkpoint | packed words]; ``block_idx``/``c``/``cutoff``
+    ``fused`` int32[n_blocks, 1, sigma + r*bits/32] rows of
+    [Occ checkpoint | packed words], as ``rank_select.place_rows`` stores
+    them (int32[n_blocks, sigma + r*bits/32] also works, at the cost of a
+    relayout of the table in the calling program); ``block_idx``/``c``/``cutoff``
     int32[B] -> int32[B].  ``bits`` in {2, 4} is the packed field width.
     ``impl``: None -> backend default ("pallas" on TPU, "jnp" popcount
     fallback elsewhere); "interpret" runs the kernel in interpret mode for

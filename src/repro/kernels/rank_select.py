@@ -11,6 +11,15 @@ so a single row fetch (one cache line / one DMA) yields both the rank base
 and the block payload — the interleaved-checkpoint struct of classic
 cache-aware FM indexes.
 
+Row-table contract: the kernel reads the table as ``int32[n_blocks, 1,
+sigma + W]``, one ``(1, sigma + W)`` block per grid step.  ``place_rows``
+stores a table that way once, where it is made: that shape, row-major, in
+``(1, 128)`` tiles (``ROW_LAYOUT``), so each table row is one lane row of
+HBM and the kernel's DMA reads the table as it lies.  A 2-D ``int32[nb,
+w]`` table (a ``DistFMIndex`` shard, the unpacked ``bwt`` blocks) still
+works: the kernel views it as ``(nb, 1, w)`` itself, and XLA then relays
+the whole table out to that layout inside every calling program.
+
 ``rank_packed_pallas`` is the fused kernel: the fused array stays in HBM
 and each inner grid step DMAs the one row its scalar-prefetched block index
 selects, then counts matches popcount-style over the packed words instead
@@ -29,11 +38,44 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.layout import Format, Layout
 from jax.experimental.pallas import tpu as pltpu
 
 # LSB of every 2-bit / 4-bit field — replicating a symbol across fields is
 # one multiply; a field equals the symbol iff its XOR-difference is zero.
 _REP = {2: 0x55555555, 4: 0x11111111}
+
+
+# the rank kernel's row-table layout: row-major, one padded lane row per
+# table row
+ROW_LAYOUT = Layout(major_to_minor=(0, 1, 2), tiling=((1, 128),))
+
+
+def _rows_3d(rows: jax.Array) -> jax.Array:
+    """int32[nb, w] -> the kernel's (nb, 1, w) view; 3-D tables as they are."""
+    if rows.ndim == 2:
+        return rows.reshape(rows.shape[0], 1, rows.shape[1])
+    return rows
+
+
+def rows_placed(rows: jax.Array) -> bool:
+    """Whether a row table is stored as the rank kernel reads it: 3-D, in
+    ``ROW_LAYOUT`` (read from the array's format, on the host)."""
+    if rows.ndim != 3:
+        return False
+    layout = rows.format.layout
+    return (tuple(layout.major_to_minor) == ROW_LAYOUT.major_to_minor
+            and tuple(map(tuple, layout.tiling)) == ROW_LAYOUT.tiling)
+
+
+def place_rows(rows: jax.Array) -> jax.Array:
+    """A row table int32[nb, w] or int32[nb, 1, w], stored as the rank
+    kernel reads it: int32[nb, 1, w] in ``ROW_LAYOUT`` on the device it is
+    on.  The one placement of packed row tables; eager only."""
+    rows = _rows_3d(rows)
+    if rows_placed(rows):
+        return rows
+    return jax.device_put(rows, Format(ROW_LAYOUT, rows.sharding))
 
 
 def packed_bits(sigma: int, sample_rate: int) -> int:
@@ -84,11 +126,13 @@ def _cutoff_mask(word_iota, cutoff, bits: int):
 def rank_packed_jnp(fused, block_idx, c, cutoff, *, bits: int, sigma: int):
     """Pure-jnp popcount rank over the fused layout (CPU fallback).
 
-    fused int32[nb, sigma + W]; block_idx/c/cutoff int32[B].
-    Returns int32[B]: Occ checkpoint + count of c in the first ``cutoff``
-    symbols of the selected block.
+    fused int32[nb, 1, sigma + W] (or int32[nb, sigma + W]);
+    block_idx/c/cutoff int32[B].  Returns int32[B]: Occ checkpoint + count
+    of c in the first ``cutoff`` symbols of the selected block.
     """
-    rows = fused[block_idx]                                  # (B, sigma+W)
+    rows = fused[block_idx]
+    if rows.ndim == 3:
+        rows = rows[:, 0]                                    # (B, sigma+W)
     base = jnp.take_along_axis(rows, c[:, None], axis=1)[:, 0]
     w = lax.bitcast_convert_type(rows[:, sigma:], jnp.uint32)  # (B, W)
     rep = jnp.uint32(_REP[bits])
@@ -137,17 +181,20 @@ def _row_gather_call(row_count, rows, block_idx, c, cutoff, *, name: str,
     """One rank query per inner grid step over an HBM-resident row table;
     ``name`` is the kernel's name in a device trace.
 
-    ``rows`` int32[nb, w] stays in HBM; the scalar-prefetched ``block_idx``
-    drives the input index map, so each step DMAs only the selected row,
-    viewed as a (1, w) block of the 3-D (nb, 1, w) array (its last two dims
-    equal the array's, which the TPU tiling rule admits for any w).  Each
-    outer step writes one lane-aligned (1, Q) output row.  B must be a
-    multiple of ``queries_per_step`` (ops.py pads).
+    ``rows`` int32[nb, 1, w] (as ``place_rows`` stores it) or int32[nb, w]
+    stays in HBM; the scalar-prefetched ``block_idx`` drives the input
+    index map, so each step DMAs only the selected row, a (1, w) block of
+    the 3-D (nb, 1, w) array (its last two dims equal the array's, which
+    the TPU tiling rule admits for any w).  A 2-D table is viewed as that
+    3-D array here, which costs a relayout of the whole table per call.
+    Each outer step writes one lane-aligned (1, Q) output row.  B must be
+    a multiple of ``queries_per_step`` (ops.py pads).
     """
     B = block_idx.shape[0]
     Q = queries_per_step
     assert B % Q == 0, (B, Q)
-    nb, wid = rows.shape
+    rows = _rows_3d(rows)
+    wid = rows.shape[2]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B // Q, Q),
@@ -164,7 +211,7 @@ def _row_gather_call(row_count, rows, block_idx, c, cutoff, *, name: str,
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name=name,
-    )(block_idx, c, cutoff, rows.reshape(nb, 1, wid))
+    )(block_idx, c, cutoff, rows)
     return out.reshape(B)
 
 
@@ -173,8 +220,9 @@ def rank_packed_pallas(fused, block_idx, c, cutoff, *, bits: int, sigma: int,
     """Fused rank kernel over the packed layout: Occ checkpoint base +
     XOR/popcount in-block count, one fused row fetched per query.
 
-    The fused array stays in HBM (a genome-scale index does not fit VMEM);
-    B must be a multiple of ``queries_per_step`` (ops.py pads).
+    The fused array, int32[nb, 1, sigma + W] as ``place_rows`` stores it
+    (a 2-D table is viewed so), stays in HBM (a genome-scale index does
+    not fit VMEM); B must be a multiple of ``queries_per_step`` (ops.py pads).
     """
     row_count = functools.partial(_packed_row_count, bits=bits, sigma=sigma)
     return _row_gather_call(row_count, fused, block_idx, c, cutoff,

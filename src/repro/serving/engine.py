@@ -14,6 +14,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from ..configs.base import ArchConfig
+from ..kernels.rank_select import rows_placed
 from ..models import transformer as tf
 from ..sharding import MeshContext
 
@@ -109,8 +110,9 @@ class FMQueryServer:
     Profiler spans: ``fm.flush`` around each flush (``requests``,
     ``groups``); inside it ``fm.dispatch`` around each padded index call
     (``kind``, ``length``, ``rows`` = padded batch, ``used`` = real
-    requests), and ``fm.fetch`` around the wait for and copy of results the
-    index returns on the device.
+    requests, ``placed`` = 1 when the packed row tables the index reads
+    are stored in the rank kernel's layout), and ``fm.fetch`` around the
+    wait for and copy of results the index returns on the device.
     """
 
     def __init__(self, index, *, length_buckets=(8, 16, 32, 64),
@@ -131,6 +133,8 @@ class FMQueryServer:
         self.completed: dict[int, FMQueryResult] = {}
         self.completed_cap = completed_cap
         self.stats = FMServerStats()
+        self._placed_for: tuple = ()  # the tables _tables_placed last read
+        self._placed = 0
 
     @classmethod
     def from_config(cls, index, cfg) -> "FMQueryServer":
@@ -146,6 +150,17 @@ class FMQueryServer:
         while b < m:  # oversize queries: next power-of-two bucket
             b *= 2
         return b
+
+    def _tables_placed(self) -> int:
+        """1 when the index's queries read packed row tables and every one
+        is in the rank kernel's layout, else 0; read from the tables'
+        formats once per set of table objects."""
+        tables = self.index.packed_row_tables()
+        if (len(tables) != len(self._placed_for)
+                or any(a is not b for a, b in zip(tables, self._placed_for))):
+            placed = bool(tables) and all(rows_placed(t) for t in tables)
+            self._placed_for, self._placed = tables, int(placed)
+        return self._placed
 
     def _bucket_batch(self, b: int) -> int:
         out = 1
@@ -199,7 +214,8 @@ class FMQueryServer:
                     chunk = items[lo : lo + self.max_batch]
                     B = self._bucket_batch(len(chunk))
                     with TraceAnnotation("fm.dispatch", kind=kind, length=L,
-                                         rows=B, used=len(chunk)):
+                                         rows=B, used=len(chunk),
+                                         placed=self._tables_placed()):
                         pats = np.full((B, L), PAD, np.int32)
                         for i, (_, pat) in enumerate(chunk):
                             pats[i, : len(pat)] = pat

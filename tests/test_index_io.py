@@ -33,6 +33,7 @@ from repro.core.index_io import (
 )
 from repro.core.pipeline import build_index
 from repro.core.suffix_array import suffix_array
+from repro.kernels.rank_select import rows_placed
 
 DRIVER = os.path.join(os.path.dirname(__file__), "dist_driver.py")
 
@@ -68,6 +69,24 @@ class TestRoundtrip:
         rest = restore_index(str(tmp_path))
         _assert_same_index(idx, rest, _random_patterns(rng, toks))
         assert rest.text_length == idx.text_length
+
+    def test_fused_saved_2d_restores_placed(self, tmp_path):
+        """The checkpoint holds the logical int32[n_blocks, sigma + W] rows
+        (the format older checkpoints have); restore places them in the
+        rank kernel's layout and answers bit-identically."""
+        rng = np.random.default_rng(10)
+        toks = rng.integers(1, 5, 900).astype(np.int32)
+        idx = build_index(toks, sample_rate=16, sa_sample_rate=8)
+        assert rows_placed(idx.fm.fused)
+        save_index(str(tmp_path), idx)
+        (npz,) = tmp_path.glob("step_*/arrays.npz")
+        with np.load(npz) as arrays:
+            saved = arrays["fused"]
+        assert saved.shape == (idx.fm.n_blocks, idx.fm.fused.shape[2])
+        assert np.array_equal(saved, np.asarray(idx.fm.fused)[:, 0])
+        rest = restore_index(str(tmp_path))
+        assert rows_placed(rest.fm.fused)
+        _assert_same_index(idx, rest, _random_patterns(rng, toks))
 
     def test_no_sa_sample(self, tmp_path):
         """Empty SA sample (sa_sample_rate=0): roundtrips, locate raises."""

@@ -110,8 +110,32 @@ def test_serving_spans(tmp_path, segments):
         assert 1 <= d.args["used"] <= d.args["rows"] <= 16
         assert d.args["kind"] in ("count", "locate")
         assert d.args["length"] in (8, 16)
+        assert d.args["placed"] == 1
         assert any(_inside(s, d) for s in spans if s.name == "fm.fetch")
     assert sum(d.args["used"] for d in dispatches) == len(pats)
+
+
+@pytest.mark.parametrize("pack,placed", [(None, 1), (False, 0)])
+def test_dispatch_placed(tmp_path, pack, placed):
+    """``fm.dispatch`` carries ``placed``: 1 for a freshly built packed
+    index, whose row table is stored in the rank kernel's layout; 0 for the
+    unpacked layout, which has no packed row table."""
+    rng = np.random.default_rng(12)
+    text = rng.integers(1, SIGMA, 2000).astype(np.int32)
+    index = build_index(text, sample_rate=16, sa_sample_rate=8, pack=pack)
+    assert bool(index.fm.bits) == (pack is None)
+    server = FMQueryServer(index, length_buckets=(8,), max_batch=8,
+                           locate_k=4)
+    pats = [text[i : i + 6] for i in range(0, 60, 5)]
+
+    def work():
+        server.count(pats)
+        return server.locate(pats)
+
+    spans, _ = _trace(tmp_path, work)
+    dispatches = [s for s in spans if s.name == "fm.dispatch"]
+    assert {d.args["kind"] for d in dispatches} == {"count", "locate"}
+    assert [d.args["placed"] for d in dispatches] == [placed] * len(dispatches)
 
 
 def test_build_spans(tmp_path):

@@ -6,6 +6,8 @@ interpret mode, and the jnp popcount fallback) plus a naive unpack-and-scan
 oracle in kernels/ref.py; they must agree bit-for-bit on random batches.
 """
 
+import dataclasses
+
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -22,7 +24,12 @@ from repro.core.fm_index import (
 )
 from repro.core.suffix_array import suffix_array
 from repro.kernels import ops, ref
-from repro.kernels.rank_select import pack_words, packed_bits
+from repro.kernels.rank_select import (
+    pack_words,
+    packed_bits,
+    place_rows,
+    rows_placed,
+)
 
 
 def _fused_fixture(rng, bits, sigma, nblocks, r):
@@ -82,6 +89,61 @@ class TestPackedRankParity:
             want = occ[[0, 3], 2] + (
                 syms.reshape(4, 8)[[0, 3], :cutv] == 2).sum(axis=1)
             assert np.array_equal(got, want), cutv
+
+
+class TestPlacedRowTable:
+    """The row table placed in the rank kernel's layout, int32[nb, 1, w],
+    answers bit-identically to the logical int32[nb, w] rows."""
+
+    @pytest.mark.parametrize("bits,sigma,r", [(2, 4, 16), (4, 6, 64)])
+    @pytest.mark.parametrize("impl", ["jnp", "interpret"])
+    def test_rank_packed_placed_matches_ref(self, bits, sigma, r, impl):
+        rng = np.random.default_rng(bits + sigma + r)
+        nblocks = 19
+        fused, _, _ = _fused_fixture(rng, bits, sigma, nblocks, r)
+        placed = place_rows(fused)
+        assert placed.shape == (nblocks, 1, fused.shape[1])
+        assert rows_placed(placed) and not rows_placed(fused)
+        B = 37
+        bidx = jnp.asarray(rng.integers(0, nblocks, B).astype(np.int32))
+        c = jnp.asarray(rng.integers(0, sigma, B).astype(np.int32))
+        cut = jnp.asarray(rng.integers(0, r + 1, B).astype(np.int32))
+        kw = dict(bits=bits, sigma=sigma)
+        want = np.asarray(ref.rank_packed_ref(fused, bidx, c, cut, **kw))
+        got = np.asarray(ops.rank_packed(placed, bidx, c, cut, impl=impl,
+                                         **kw))
+        assert np.array_equal(got, want)
+
+    def test_place_rows_is_idempotent(self):
+        fused, _, _ = _fused_fixture(np.random.default_rng(1), 4, 7, 5, 8)
+        placed = place_rows(fused)
+        assert place_rows(placed) is placed
+        assert np.array_equal(np.asarray(placed)[:, 0], np.asarray(fused))
+
+    @pytest.mark.parametrize("sigma_hi,sample_rate", [(4, 32), (16, 16)])
+    def test_count_and_locate_of_built_index(self, sigma_hi, sample_rate):
+        rng = np.random.default_rng(40 + sigma_hi)
+        fm, s, sa = _build(rng, sigma_hi, 500, sample_rate)
+        assert fm.bits and rows_placed(fm.fused)
+        assert fm.fused.shape == (fm.n_blocks, 1,
+                                  fm.sigma + sample_rate * fm.bits // 32)
+        flat = dataclasses.replace(
+            fm, fused=jnp.asarray(np.asarray(fm.fused)[:, 0]))
+        unpacked, _, _ = _build(np.random.default_rng(40 + sigma_hi),
+                                sigma_hi, 500, sample_rate, pack=False)
+        B, L = 16, 5
+        pats = np.full((B, L), PAD, np.int32)
+        lens = rng.integers(1, L + 1, B)
+        for i, m in enumerate(lens):
+            pats[i, :m] = rng.integers(1, max(2, sigma_hi), m)
+        pats = jnp.asarray(pats)
+        got = np.asarray(count(fm, pats))
+        assert np.array_equal(got, np.asarray(count(flat, pats)))
+        assert list(got) == [count_naive(s, np.asarray(pats)[i, :lens[i]])
+                             for i in range(B)]
+        pos, cnt = (np.asarray(x) for x in locate(fm, pats, 8))
+        pos_u, cnt_u = (np.asarray(x) for x in locate(unpacked, pats, 8))
+        assert np.array_equal(pos, pos_u) and np.array_equal(cnt, cnt_u)
 
 
 def _build(rng, sigma_hi, n, sample_rate, srate=8, pack=None):

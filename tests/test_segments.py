@@ -8,6 +8,7 @@ import pytest
 from repro.core.fm_index import PAD
 from repro.core.pipeline import build_index
 from repro.core.segments import SegmentedIndex
+from repro.kernels.rank_select import rows_placed
 from repro.serving.engine import FMQueryServer
 
 SIGMA = 7  # tokens 1..6
@@ -421,6 +422,29 @@ class TestMergeEdgeCases:
         seq = seg.count(pats)
         seg.parallel = True
         assert np.array_equal(got, seq)
+
+    def test_stacked_row_table_stays_placed(self):
+        """Stacking, the in-place append and the compaction splice each
+        leave the stacked row table in the rank kernel's layout, and it is
+        the one table the catalog's queries read."""
+        rng = np.random.default_rng(41)
+        seg = SegmentedIndex(SIGMA, sample_rate=16, sa_sample_rate=8,
+                             parallel=True)
+        seg.append(rng.integers(1, SIGMA, 700).astype(np.int32))
+        for n in (40, 50, 30):
+            seg.append(rng.integers(1, SIGMA, n).astype(np.int32))
+
+        def table():
+            (t,) = seg.packed_row_tables()
+            assert t is seg._stacked_cache.fused
+            return t
+
+        assert rows_placed(table())
+        assert seg.compact(min_tokens=100, strategy="merge") == 1
+        assert rows_placed(table())
+        seg.append(rng.integers(1, SIGMA, 35).astype(np.int32))
+        assert rows_placed(table())
+        assert all(rows_placed(s.index.fm.fused) for s in seg.segments)
 
 
 class TestKWayAndPlanner:

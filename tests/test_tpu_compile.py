@@ -13,9 +13,11 @@ import os
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.experimental.layout import Format
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ops
+from repro.kernels.rank_select import ROW_LAYOUT
 
 
 @pytest.fixture(scope="module")
@@ -65,16 +67,67 @@ def _assert_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _placed_table(one_chip, rows: int):
+    """The DNA row table as ``build_fm_index`` stores it: int32[rows, 1,
+    sigma 7 + 8 packed words] in the rank kernel's layout."""
+    return _sds((rows, 1, 15), jnp.int32, Format(ROW_LAYOUT, one_chip))
+
+
+@pytest.mark.parametrize("table", ["placed", "2d"])
 @pytest.mark.parametrize("B", [1, 1024])
-def test_rank_packed_compiles(one_chip, no_compile_cache, B):
+def test_rank_packed_compiles(one_chip, no_compile_cache, B, table):
     """DNA layout of a 2^26-token index: 2^20 fused rows of sigma 7 + 8
-    packed words, queried one walker (merge walk) or a serving batch."""
+    packed words, queried one walker (merge walk) or a serving batch; the
+    table placed as ``build_fm_index`` stores it, or 2-D as a
+    ``DistFMIndex`` shard holds it."""
     q = _sds((B,), jnp.int32, one_chip)
+    rows = (_placed_table(one_chip, 1 << 20) if table == "placed"
+            else _sds((1 << 20, 15), jnp.int32, one_chip))
     compiled = ops.rank_packed.lower(
-        _sds((1 << 20, 15), jnp.int32, one_chip), q, q, q,
-        bits=4, sigma=7, impl="pallas",
+        rows, q, q, q, bits=4, sigma=7, impl="pallas",
     ).compile()
     _assert_kernel(compiled)
+
+
+def _table_copies(text: str, rows: int) -> list[str]:
+    """Instructions of a compiled program that copy a whole row table of
+    ``rows`` rows, 2-D or 3-D, in any computation."""
+    shapes = (f"s32[{rows},1,15]", f"s32[{rows},15]")
+    return [line.strip() for line in text.splitlines()
+            if any(sh in line for sh in shapes)
+            and any(op in line for op in (" copy(", " copy-start(",
+                                          " copy-done("))]
+
+
+def test_query_programs_do_not_copy_the_table(one_chip, no_compile_cache,
+                                              monkeypatch):
+    """``count`` (B 512, m 64) and ``locate`` (B 128, m 32, k 16) over the
+    DNA cell's index, 2^20 + 1 rows of the placed table: the compiled
+    programs read the table where it lies, with no relayout copy of it in
+    the entry computation or in any loop body.  The backend here is the
+    CPU, so the test steers the kernel dispatch to the chip's defaults."""
+    from repro.core import fm_index
+
+    monkeypatch.setattr(ops, "kernels_on_chip", lambda: True)
+    nb, sigma, r = (1 << 20) + 1, 7, 64
+    n = nb * r
+
+    def vec(size):
+        return _sds((size,), jnp.int32, one_chip)
+
+    fm = fm_index.FMIndex(
+        vec(n), _sds((), jnp.int32, one_chip), vec(sigma),
+        _sds((nb + 1, sigma), jnp.int32, one_chip), _placed_table(one_chip, nb),
+        vec(n // 32), vec(n // 32), vec(n // 32 + 1),
+        r, sigma, n - 1, 4, 32, 21,
+    )
+    count = fm_index.count.lower(
+        fm, _sds((512, 64), jnp.int32, one_chip)).compile().as_text()
+    locate = fm_index.locate.lower(
+        fm, _sds((128, 32), jnp.int32, one_chip), 16).compile().as_text()
+    for text in (count, locate):
+        assert "tpu_custom_call" in text
+        assert _table_copies(text, nb) == []
 
 
 def test_rank_unpacked_compiles(one_chip, no_compile_cache):
